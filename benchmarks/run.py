@@ -83,6 +83,8 @@ def main(argv=None) -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="merge the emitted rows into a JSON file")
     args = ap.parse_args(argv)
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else set(SUITES)
     _rows.clear()
 

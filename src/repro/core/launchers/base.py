@@ -70,11 +70,19 @@ class Launcher(abc.ABC):
                 if not addr.is_resolved:
                     self.address_table.assign(addr, self._assign_address(node, i))
 
+        # Device hand-out: executables that run on devices (mesh workers
+        # expose ``num_devices``) take consecutive devices in launch order.
+        next_device = 0
         for gname, group in program.groups.items():
             for node in group.nodes:
                 executables = node.to_executables(
                     requirements=group.requirements,
                     launch_type=self.launch_type)
+                for ex in executables:
+                    n = getattr(ex, "num_devices", 0)
+                    if n:
+                        ex.device_offset = next_device
+                        next_device += n
                 self._execute(node, gname, executables)
         self._program = program
         return self

@@ -29,6 +29,17 @@ from repro.core.launchers.thread import PortReservation
 from repro.core.nodes.base import Executable, Node, WorkerContext
 
 
+def _tpu_host() -> bool:
+    """Whether JAX in this process would claim TPU chips, decided without
+    initializing a backend (which would itself take the chips)."""
+    import jax
+    platforms = jax.config.jax_platforms
+    if platforms and "tpu" not in platforms.split(","):
+        return False
+    from jax._src import hardware_utils
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0] > 0
+
+
 def _child_main(payload: bytes, stop_event, node_name: str) -> None:
     """Child entry point. ``payload`` is a cloudpickled executable."""
     executable: Executable = cloudpickle.loads(payload)
@@ -91,6 +102,14 @@ class ProcessLauncher(Launcher):
 
     def _execute(self, node: Node, group_name: str,
                  executables: list[Executable]) -> None:
+        if any(getattr(ex, "num_devices", 0) for ex in executables) \
+                and _tpu_host():
+            raise RuntimeError(
+                f"{node.name}: a MeshWorkerNode cannot run as a child "
+                "process on a TPU host — a chip belongs to one process, and "
+                "a forked child cannot take a chip its parent holds. Launch "
+                "this program with ThreadLauncher: one process drives every "
+                "chip, one replica per device.")
         for ex in executables:
             managed = _Managed(node.name, group_name, cloudpickle.dumps(ex))
             self._managed.append(managed)

@@ -1,6 +1,6 @@
 """MeshWorkerNode: an SPMD (pjit) worker as a Launchpad service.
 
-This is the TPU-pod adaptation of the paper's model (DESIGN.md §2): the
+This is the TPU-pod adaptation of the paper's model: the
 Launchpad graph is the *control plane*; inside a MeshWorkerNode the *data
 plane* is a pjit-compiled step over a device mesh. The node behaves like a
 CourierNode (deferred constructor, courier handle), but the resource
@@ -14,7 +14,11 @@ the service as a constructed ``jax.sharding.Mesh``::
 
 The wrapped class receives ``mesh=<Mesh>`` as a keyword argument. On a real
 multi-host platform the launcher would also set the jax distributed env
-per host; the single-machine launchers build the mesh from local devices.
+per host; the single-machine launchers build the mesh from local devices,
+handing ``jax.devices()`` out in launch order: each mesh worker takes the
+next ``prod(mesh)`` devices (``device_offset``), so N one-device workers
+on an N-chip host get a chip each. Workers that outnumber the devices
+wrap around and share them.
 """
 
 from __future__ import annotations
@@ -35,21 +39,31 @@ class _MeshExecutable(Executable):
         self._address = address
         self._mesh_shape = mesh_shape
         self._mesh_axes = mesh_axes
+        self.device_offset = 0          # set by the launcher
+
+    @property
+    def num_devices(self) -> int:
+        n = 1
+        for s in self._mesh_shape:
+            n *= s
+        return n
 
     def _build_mesh(self):
         import jax
-        n_need = 1
-        for s in self._mesh_shape:
-            n_need *= s
-        n_have = len(jax.devices())
-        if n_have < n_need:
+        devices = jax.devices()
+        n_need = self.num_devices
+        if len(devices) < n_need:
             raise RuntimeError(
                 f"mesh {self._mesh_shape} needs {n_need} devices, "
-                f"host platform has {n_have} "
+                f"host platform has {len(devices)} "
                 "(set XLA_FLAGS=--xla_force_host_platform_device_count=N "
                 "before jax initializes, or shrink the mesh resource)")
-        from repro.sharding.compat import make_mesh
-        return make_mesh(self._mesh_shape, self._mesh_axes)
+        picked = [devices[(self.device_offset + i) % len(devices)]
+                  for i in range(n_need)]
+        return jax.make_mesh(
+            self._mesh_shape, self._mesh_axes,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(self._mesh_axes),
+            devices=picked)
 
     def run(self, context: WorkerContext) -> None:
         from repro.core import courier

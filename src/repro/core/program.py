@@ -81,13 +81,21 @@ class Program:
         out = []
         for node in self.nodes:
             for h in node.input_handles:
-                owner = self._handle_owner.get(id(h))
+                owner = self.owner_of(h)
                 if owner is not None:
                     out.append((node, owner))
         return out
 
     def owner_of(self, handle: Handle) -> Optional[Node]:
-        return self._handle_owner.get(id(handle))
+        owner = self._handle_owner.get(id(handle))
+        if owner is None:
+            # A handle minted from a node after add_node (e.g. a driver
+            # wired into a program that a builder function returned).
+            for node in self.nodes:
+                if any(h is handle for h in node._created_handles):
+                    self._handle_owner[id(handle)] = owner = node
+                    break
+        return owner
 
     def validate(self) -> None:
         """Structural checks run by launchers before anything starts."""
@@ -96,7 +104,7 @@ class Program:
             raise ValueError(f"Duplicate node names in program: {names}")
         for node in self.nodes:
             for h in node.input_handles:
-                if id(h) not in self._handle_owner:
+                if self.owner_of(h) is None:
                     raise ValueError(
                         f"Node {node.name!r} consumes a handle that does not "
                         "belong to any node in this program")

@@ -7,6 +7,14 @@ traffic is exactly one pass over K and V (the roofline minimum for
 decode). Ring-buffer validity (which slots hold live tokens, window
 eviction) arrives as a precomputed ``valid`` mask — the kernel is layout
 agnostic. GQA is native via the index_map (h // group).
+
+Block layout (Mosaic tiles the last two dims of every block by (8, 128)
+unless a block dim equals the array dim): the mask rides as
+``[B, nblk, 1, blk]`` so each block's last two dims ``(1, blk)`` equal
+the array's, whatever ``B`` is; K/V blocks are ``(blk, dh)``, so the
+flat kernel needs ``blk`` a multiple of 128 (or the whole length) —
+``kernels.ops`` pads the length up to that — and the paged kernel's
+``(ps, dh)`` page block equals the pool's own trailing dims.
 """
 
 from __future__ import annotations
@@ -33,7 +41,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    valid = valid_ref[0] != 0                        # [bl]
+    valid = valid_ref[0, 0] != 0                     # [1, bl]
 
     @pl.when(jnp.any(valid))
     def _compute():
@@ -43,13 +51,13 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale                              # [1, bl]
-        s = jnp.where(valid[None, :], s, NEG_INF)
+        s = jnp.where(valid, s, NEG_INF)
 
         m_prev = m_scr[...]                           # [1]
         m_new = jnp.maximum(m_prev, s.max(axis=1))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(valid[None, :], p, 0.0)
+        p = jnp.where(valid, p, 0.0)
         l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
         acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
@@ -67,21 +75,28 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      sm_scale: Optional[float] = None,
                      block_l: int = 512,
                      interpret: bool = False) -> jax.Array:
-    """q [B,H,dh]; k/v [B,L,KV,dh]; valid [B,L] (bool/int) -> [B,H,dh]."""
+    """q [B,H,dh]; k/v [B,L,KV,dh]; valid [B,L] (bool/int) -> [B,H,dh].
+
+    ``L`` must be a multiple of ``block_l``; on TPU ``block_l`` must also
+    be a multiple of 128 or equal ``L`` (``kernels.ops`` pads to that).
+    """
     B, H, dh = q.shape
     L, KV = k.shape[1], k.shape[2]
     assert H % KV == 0
     group = H // KV
     sm_scale = sm_scale if sm_scale is not None else dh ** -0.5
     block_l = min(block_l, L)
-    assert L % block_l == 0, (L, block_l)
+    if L % block_l:
+        raise ValueError(f"cache length {L} is not a multiple of the "
+                         f"block {block_l}")
+    nblk = L // block_l
 
     qt = q[:, :, None, :]                     # [B, H, 1, dh]
     kt = k.transpose(0, 2, 1, 3)              # [B, KV, L, dh]
     vt = v.transpose(0, 2, 1, 3)
-    valid_i = valid.astype(jnp.int32)
+    valid_i = valid.astype(jnp.int32).reshape(B, nblk, 1, block_l)
 
-    grid = (B, H, L // block_l)
+    grid = (B, H, nblk)
     kernel = functools.partial(_decode_kernel, sm_scale=sm_scale)
     out = pl.pallas_call(
         kernel,
@@ -92,7 +107,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          lambda b, h, j: (b, h // group, j, 0)),
             pl.BlockSpec((1, 1, block_l, dh),
                          lambda b, h, j: (b, h // group, j, 0)),
-            pl.BlockSpec((1, block_l), lambda b, h, j: (b, j)),
+            pl.BlockSpec((1, 1, 1, block_l), lambda b, h, j: (b, j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, dh), lambda b, h, j: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, 1, dh), q.dtype),
@@ -130,7 +145,8 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     operand so the K/V index maps can point the DMA at the physical page
     ``pages[b, j]`` — the gather never materializes. The ``valid`` mask is
     logical-slot indexed, so its index map stays (b, j). Online-softmax
-    state in VMEM scratch, identical to the flat kernel.
+    state in VMEM scratch, identical to the flat kernel. Which page sizes
+    the chip can tile is ``kernels.ops.paged_page_size_ok``'s rule.
     """
     B, H, dh = q.shape
     P, ps, KV = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
@@ -143,7 +159,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     qt = q[:, :, None, :]                       # [B, H, 1, dh]
     kt = k_pages.transpose(2, 0, 1, 3)          # [KV, P, ps, dh]
     vt = v_pages.transpose(2, 0, 1, 3)
-    valid_i = valid.astype(jnp.int32)
+    valid_i = valid.astype(jnp.int32).reshape(B, n, 1, ps)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -154,7 +170,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                          lambda b, h, j, pg: (h // group, pg[b, j], 0, 0)),
             pl.BlockSpec((1, 1, ps, dh),
                          lambda b, h, j, pg: (h // group, pg[b, j], 0, 0)),
-            pl.BlockSpec((1, ps), lambda b, h, j, pg: (b, j)),
+            pl.BlockSpec((1, 1, 1, ps), lambda b, h, j, pg: (b, j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, dh),
                                lambda b, h, j, pg: (b, h, 0, 0)),

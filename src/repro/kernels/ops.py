@@ -1,20 +1,18 @@
 """jit'd public wrappers for the Pallas kernels.
 
 ``interpret`` defaults to auto: real kernels on TPU, interpret-mode
-execution elsewhere (this container is CPU-only — interpret mode runs the
-kernel body in Python for correctness validation; see DESIGN.md §7).
+execution on other backends (interpret mode runs the kernel body op by op
+for correctness validation).
 
 ``decode_attention`` is the one wrapper on a serving hot path (the engine
 calls it every token), so it carries a backend-aware dispatch table
 instead of a bare jit: the Pallas kernel on TPU, the pure-jnp oracle as a
-real XLA executable everywhere else, with ``REPRO_FORCE_REF=1`` as the
-production escape hatch. See README.md in this directory.
+real XLA executable everywhere else. See README.md in this directory.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -43,36 +41,55 @@ def flash_attention(q, k, v, causal: bool = True,
                                interpret=interpret)
 
 
-def _pick_block_l(L: int, want: int) -> int:
-    """Largest divisor of L that is <= want (the kernel tiles L evenly)."""
-    b = min(want, L)
-    while L % b:
-        b -= 1
-    return b
+LANE = 128      # TPU vector lane width: minor block dims tile by this
+
+
+def _cdiv(a: int, b: int) -> int:
+    return -(-a // b)
+
+
+def _block_l(L: int, want: int) -> int:
+    """Flat-kernel block along the cache length: the whole length when it
+    fits one block (a block equal to the array dim always tiles), else the
+    lane-aligned block that covers L in ``ceil(L / want)`` blocks with the
+    least padding."""
+    nblk = _cdiv(L, want)
+    if nblk == 1:
+        return L
+    return _cdiv(_cdiv(L, nblk), LANE) * LANE
+
+
+# Mosaic's default per-kernel VMEM budget on TPU v5e.
+SCOPED_VMEM_BYTES = 16 * 2 ** 20
+
+
+def paged_page_size_ok(page_size: int, head_dim: int) -> bool:
+    """Whether the paged kernel can tile ``page_size`` on TPU. One grid
+    step holds a ``(page_size, head_dim)`` bf16 K block and V block plus
+    the page's ``(1, page_size)`` int32 mask block (padded to 8 sublanes),
+    all double-buffered; they must fit the kernel's VMEM budget."""
+    per_step = 2 * page_size * head_dim * 2 + 8 * page_size * 4
+    return 2 * per_step <= SCOPED_VMEM_BYTES
 
 
 def resolve_decode_impl(impl: Optional[str] = None,
                         interpret: Optional[bool] = None) -> str:
     """Dispatch rule for ``decode_attention``.
 
-    Explicit ``impl`` wins (tests pin a path). Otherwise ``REPRO_FORCE_REF=1``
-    forces the oracle (the escape hatch when a kernel miscompile is
-    suspected in production), an explicit ``interpret`` flag selects the
-    Pallas body (that flag only means something to the kernel), and the
-    default is backend-driven: the real kernel on TPU, the jnp oracle —
-    a fast native XLA executable, not Python interpret mode — elsewhere.
+    Explicit ``impl`` wins (tests pin a path). Otherwise an explicit
+    ``interpret`` flag selects the Pallas body (that flag only means
+    something to the kernel), and the default is backend-driven: the real
+    kernel on TPU, the jnp oracle — a fast native XLA executable, not
+    Python interpret mode — elsewhere.
 
     NOTE: when called inside a traced function the choice is baked into
-    that executable at trace time (env var and backend are host state);
-    the serve engine keys its executable caches on the impl for this
-    reason.
+    that executable at trace time (the backend is host state); the serve
+    engine keys its executable caches on the impl for this reason.
     """
     if impl is not None:
         if impl not in ("pallas", "ref"):
             raise ValueError(f"impl must be 'pallas' or 'ref', got {impl!r}")
         return impl
-    if os.environ.get("REPRO_FORCE_REF", "") == "1":
-        return "ref"
     if interpret is not None:
         return "pallas"
     return "pallas" if jax.default_backend() == "tpu" else "ref"
@@ -93,13 +110,21 @@ def decode_attention(q, k, v, valid, block_l: int = 512,
     """Backend-dispatched single-token attention (see resolve_decode_impl).
 
     q [B,H,dh]; k/v [B,L,KV,dh]; valid [B,L] bool -> [B,H,dh]. Both paths
-    share one contract, including all-invalid rows -> zeros.
+    share one contract, including all-invalid rows -> zeros. Any ``L`` is
+    accepted: the kernel path pads the cache to a whole number of
+    lane-aligned blocks (pad slots are invalid, so they never count).
     """
     if resolve_decode_impl(impl, interpret) == "ref":
         return _decode_ref(q, k, v, valid)
     interpret = _auto_interpret() if interpret is None else interpret
-    return _decode_pallas(q, k, v, valid,
-                          _pick_block_l(k.shape[1], block_l), interpret)
+    L = k.shape[1]
+    bl = _block_l(L, block_l)
+    pad = -L % bl
+    if pad:
+        widths = ((0, 0), (0, pad), (0, 0), (0, 0))
+        k, v = jnp.pad(k, widths), jnp.pad(v, widths)
+        valid = jnp.pad(valid, ((0, 0), (0, pad)))
+    return _decode_pallas(q, k, v, valid, bl, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

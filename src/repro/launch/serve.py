@@ -44,6 +44,11 @@ Two serving modes share the single-engine topology (``--mode``):
     PYTHONPATH=src python -m repro.launch.serve --requests 12
     PYTHONPATH=src python -m repro.launch.serve --mode lockstep
     PYTHONPATH=src python -m repro.launch.serve --replicas 2 --routers 1
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b  # published
+
+With no ``--arch`` the program serves the reduced qwen2-1.5b preset;
+``--arch NAME`` serves that architecture at its published widths and
+``--reduced`` its reduced preset.
 """
 
 from __future__ import annotations
@@ -59,6 +64,7 @@ import numpy as np
 
 from repro import configs, core as lp
 from repro.core import telemetry
+from repro.launch.cache import enable_compile_cache
 from repro.models.config import ModelConfig
 from repro.serve import decode as serve_lib
 from repro.serve.router import Router, decorrelated_backoff, is_overloaded
@@ -115,6 +121,16 @@ class EngineServer:
     FaultInjector's softer weapons (missed beats / transport blackhole
     for a window, then recovery).
 
+    The replica lives on its mesh's one device (the launcher hands each
+    mesh worker its own): params, KV state and the engine's device-resident
+    feed state are placed there, so N replicas on an N-chip host use a
+    chip each. The engine thread compiles its decode ladder (``warmup``)
+    before it serves, and the replica registers with the registry only
+    once that is done, so routers never dispatch to a replica that is
+    still compiling; the seconds land in the ``engine.warmup_s``
+    histogram. The constructor returns before the warm-up, so direct
+    callers find the service at once and their requests queue.
+
     With ``store_dir`` set, weights load from a versioned
     :class:`~repro.ckpt.checkpoint.ModelStore` (``version=None`` means
     latest) instead of fresh init, and ``load_version()`` hot-swaps to
@@ -138,6 +154,7 @@ class EngineServer:
                  prefix_cache: bool = True,
                  store_dir: str | None = None,
                  version: int | None = None):
+        import contextlib
         import jax
         from repro.models import transformer
         from repro.serve.engine import ServeEngine
@@ -146,7 +163,17 @@ class EngineServer:
         self._store = None
         self._version: int | None = None
         self._drop_until = 0.0
-        params = transformer.init_params(model_cfg, jax.random.key(0))
+        device = None
+        if mesh is not None:
+            if mesh.size != 1:
+                raise ValueError(
+                    f"EngineServer runs one replica on one device; got a "
+                    f"{mesh.size}-device mesh {dict(mesh.shape)}")
+            device = mesh.devices.flat[0]
+        on_device = (contextlib.nullcontext() if device is None
+                     else jax.default_device(device))
+        with on_device:
+            params = transformer.init_params(model_cfg, jax.random.key(0))
         if store_dir is not None:
             from repro.ckpt.checkpoint import ModelStore
             self._store = ModelStore(store_dir)
@@ -162,8 +189,8 @@ class EngineServer:
             max_new=max_new, eos_id=eos_id, sync_every=sync_every,
             decode_impl=decode_impl, top_k=top_k,
             prefill_chunk=prefill_chunk, page_size=page_size,
-            num_pages=num_pages, prefix_cache=prefix_cache)
-        self._engine.start()
+            num_pages=num_pages, prefix_cache=prefix_cache, device=device)
+        self._engine.start(warmup=True)
         self._heartbeater = None
         if registry is not None:
             ctx = lp.get_current_context()
@@ -175,7 +202,23 @@ class EngineServer:
                     "run it as a courier-serving node or pass endpoint=")
             self._heartbeater = lp.Heartbeater(
                 registry, name, endpoint, load_fn=self.load,
-                period_s=heartbeat_s, stop_event=ctx.stop_event).start()
+                period_s=heartbeat_s, stop_event=ctx.stop_event)
+            threading.Thread(target=self._join_fabric,
+                             args=(ctx.stop_event,), daemon=True,
+                             name=f"join/{name}").start()
+
+    def _join_fabric(self, stop_event) -> None:
+        """Start heartbeats (registration) once the engine is warm."""
+        while not (self._engine.ready.wait(0.1) or stop_event.is_set()):
+            pass
+        if self._engine.alive and not stop_event.is_set():
+            self._heartbeater.start()
+
+    def run(self):
+        """Serve until the program stops, then stop the engine: its thread
+        exits and the replica's device memory is released."""
+        lp.get_current_context().wait_for_stop()
+        self._engine.stop()
 
     def generate(self, prompt, max_new=None):
         if time.monotonic() < self._drop_until:
@@ -455,7 +498,9 @@ class Client:
 
 class Meter:
     """Collects request latencies; prints percentiles and (optionally)
-    writes the summary to a JSON file before stopping the program.
+    writes the summary to a JSON file before stopping the program. The
+    summary also carries the shortest and longest reply
+    (``out_len_min``/``out_len_max``, prompt + generated tokens).
 
     Built on the telemetry histogram layer: every record lands in a
     per-source :class:`repro.core.telemetry.Histogram` registered as
@@ -480,6 +525,7 @@ class Meter:
         self._expected = expected
         self._summary_path = summary_path
         self._hists: dict[str, telemetry.Histogram] = {}
+        self._out_len = telemetry.Histogram("meter.out_len")  # exact min/max
         self._count = 0
         self._holds = holds
         self._summary_done = False
@@ -505,6 +551,7 @@ class Meter:
                 h.reset()
                 self._hists[src] = h
             h.record(latency_s * 1e3)       # stored in ms: keys read direct
+            self._out_len.record(out_len)
             self._count += 1
             done = self._count >= self._expected and not self._summary_done
             if done:
@@ -515,6 +562,8 @@ class Meter:
             for h in self._hists.values():
                 merged.merge(h)
             summary = self._percentiles(merged)   # the merged roll-up row
+            summary["out_len_min"] = int(self._out_len.vmin)
+            summary["out_len_max"] = int(self._out_len.vmax)
             if len(self._hists) > 1 or "default" not in self._hists:
                 summary["per_source"] = {
                     src: self._percentiles(h)
@@ -741,7 +790,11 @@ class RolloutDriver:
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None)
+    ap.add_argument("--arch", default=None,
+                    help="serve this architecture at its published widths "
+                         "(default: the reduced qwen2-1.5b preset)")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the reduced preset of --arch")
     ap.add_argument("--clients", type=int, default=3)
     ap.add_argument("--requests", type=int, default=4,
                     help="requests per client")
@@ -777,8 +830,11 @@ def main(argv=None):
                     help="trace every Nth request per client end to end "
                          "(0 = tracing off)")
     args = ap.parse_args(argv)
-    cfg = (configs.get_reduced(args.arch) if args.arch
-           else configs.get_reduced("qwen2-1.5b"))
+    if args.arch and not args.reduced:
+        cfg = configs.get(args.arch)
+    else:
+        cfg = configs.get_reduced(args.arch or "qwen2-1.5b")
+    enable_compile_cache()
     store_dir, model_version, rollout = args.store, None, None
     if args.rollout_after is not None:
         import tempfile

@@ -34,6 +34,7 @@ import numpy as np
 from repro import configs, core as lp
 from repro.ckpt.checkpoint import ModelStore
 from repro.data.pipeline import DataConfig, Prefetcher, make_source
+from repro.launch.cache import enable_compile_cache
 from repro.models.config import ATTN, ModelConfig
 from repro.train.fabric import (ChaosNode, FabricConfig, LearnerWorker,
                                 ThreadWorkerSpawner, TrainSupervisor)
@@ -115,9 +116,11 @@ class FleetSupervisor:
     def _make_mesh(self):
         if self._mesh_shape is None:
             return None
-        from repro.sharding.compat import make_mesh
+        import jax
         names = ("data", "model")[: len(self._mesh_shape)]
-        return make_mesh(tuple(self._mesh_shape), names)
+        return jax.make_mesh(
+            tuple(self._mesh_shape), names,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
     def run(self):
         spawner = ThreadWorkerSpawner()
@@ -249,6 +252,7 @@ def main(argv=None):
     ap.add_argument("--mesh", default=None,
                     help="e.g. 2,1 -> data=2,model=1 (needs devices)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.arch:
         model_cfg = (configs.get_reduced(args.arch) if args.reduced

@@ -149,8 +149,6 @@ class CellCost:
 
 def cost_from_compiled(compiled, num_devices: int) -> CellCost:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
     coll = parse_collectives(compiled.as_text(), num_devices)
     return CellCost(
         flops=float(ca.get("flops", 0.0)),

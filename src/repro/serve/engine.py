@@ -144,7 +144,13 @@ class ServeEngine:
     resolving to the full sequence (prompt + generated tokens, int32).
     Drive the engine either with ``start()`` (daemon decode loop — the
     serving deployment) or by calling ``step()`` directly from one thread
-    (deterministic, used by tests and benchmarks).
+    (deterministic, used by tests and benchmarks). If a step raises in the
+    daemon loop, every queued and in-flight request fails with that
+    exception and the engine stops (``alive`` turns False).
+
+    ``device`` pins the replica to one device: params, the decode state
+    and the device-resident feed tokens/positions live there, and the
+    engine thread allocates there (default: JAX's default device).
     """
 
     def __init__(self, cfg: ModelConfig, params, *, num_slots: int = 8,
@@ -155,9 +161,10 @@ class ServeEngine:
                  prefill_chunk: Optional[int] = None,
                  page_size: Optional[int] = None,
                  num_pages: Optional[int] = None,
-                 prefix_cache: bool = True):
+                 prefix_cache: bool = True, device=None):
         import jax
         import jax.numpy as jnp
+        from repro.kernels import ops as kernel_ops
         from repro.models import transformer
         from repro.serve import decode as serve_lib
 
@@ -172,7 +179,20 @@ class ServeEngine:
                              f"got {decode_impl!r}")
         if page_size is not None and page_size < 1:
             raise ValueError("page_size must be >= 1")
+        if (page_size is not None and decode_impl != "dense"
+                and jax.default_backend() == "tpu"
+                and not kernel_ops.paged_page_size_ok(page_size,
+                                                      cfg.head_dim)):
+            raise ValueError(
+                f"page_size {page_size} cannot be tiled by the paged "
+                f"flash-decode kernel at head_dim {cfg.head_dim}: its "
+                "double-buffered K/V page blocks overflow the kernel's "
+                f"{kernel_ops.SCOPED_VMEM_BYTES >> 20} MiB VMEM budget; "
+                "use a smaller page_size")
+        self._device = device
         self._cfg = cfg
+        if device is not None:
+            params = jax.device_put(params, device)
         self._params = params
         self._ns = num_slots
         self._L = context_len
@@ -232,11 +252,15 @@ class ServeEngine:
                     f"{ring}] (the smallest cache ring) — a larger chunk "
                     "would overwrite slots its own queries still attend to")
 
+        with self._on_device():
+            self._state = self._init_state()
+            # Device-resident hot state: the feed tokens and per-row
+            # positions live on device between syncs (rebuilding them
+            # from host numpy every step was a measurable per-step tax),
+            # and the fused window threads them through donated buffers.
+            self._tokens_dev = jnp.zeros((num_slots, 1), jnp.int32)
+            self._t_dev = jnp.zeros((num_slots,), jnp.int32)
         if self._has_paged:
-            # Physical pool is P usable pages + the trash page (id 0).
-            self._state = transformer.init_decode_state(
-                cfg, num_slots, self._Lp, page_size=self._ps,
-                num_pages=self._P + 1)
             # The page table lives on HOST (tiny int32 [ns, n_log]): it
             # mutates on every admission/retirement, and host writes are
             # free where device .at[] updates were one jit call each; the
@@ -254,18 +278,10 @@ class ServeEngine:
             else:
                 self._prefix = None
         else:
-            self._state = transformer.init_decode_state(cfg, num_slots,
-                                                        self._Lp)
             self._pages_tab = None
             self._prefix = None
         self._slots: list[Optional[_Slot]] = [None] * num_slots
         self._free: list[int] = list(range(num_slots - 1, -1, -1))
-        # Device-resident hot state: the feed tokens and per-row positions
-        # live on device between syncs (rebuilding them from host numpy
-        # every step was a measurable per-step tax), and the fused window
-        # threads them through donated buffers.
-        self._tokens_dev = jnp.zeros((num_slots, 1), jnp.int32)
-        self._t_dev = jnp.zeros((num_slots,), jnp.int32)
 
         # Fused-window executables, shared across engine instances via the
         # lru cache in serve.decode (keyed on every static knob, attn_impl
@@ -317,8 +333,11 @@ class ServeEngine:
         self._wake = threading.Event()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
+        # Set once the daemon loop serves (after its warm-up) or has exited.
+        self.ready = threading.Event()
         self._closed = False
         self._lock = threading.Lock()                       # stats + lifecycle
+        self._error: Optional[BaseException] = None   # why the loop died
         self._counters = dict(submitted=0, admitted=0, retired=0, failed=0,
                               steps=0, decode_tokens=0, generated_tokens=0,
                               occupancy_sum=0, peak_occupancy=0,
@@ -334,6 +353,24 @@ class ServeEngine:
         # EWMA decode-step microseconds per token: the routing signal a
         # load balancer uses to weigh this engine against its siblings.
         self._ewma_us_tok = 0.0
+
+    def _on_device(self):
+        """Context that allocates on this engine's device."""
+        import contextlib
+        import jax
+        if self._device is None:
+            return contextlib.nullcontext()
+        return jax.default_device(self._device)
+
+    def _init_state(self):
+        """A zeroed full-width decode state: the page pool (P usable
+        pages + the trash page, id 0) in paged mode, else flat rings."""
+        from repro.models import transformer
+        if self._has_paged:
+            return transformer.init_decode_state(
+                self._cfg, self._ns, self._Lp, page_size=self._ps,
+                num_pages=self._P + 1)
+        return transformer.init_decode_state(self._cfg, self._ns, self._Lp)
 
     # -- client side ---------------------------------------------------------
     def submit(self, prompt, max_new: Optional[int] = None) -> cf.Future:
@@ -365,7 +402,8 @@ class ServeEngine:
             # draining, so a request can never slip into the queue after
             # the drain and hang its caller.
             if self._closed:
-                fut.set_exception(RuntimeError("engine stopped"))
+                fut.set_exception(self._error
+                                  or RuntimeError("engine stopped"))
                 return fut
             self._counters["submitted"] += 1
             ctx = telemetry.current_context()
@@ -411,6 +449,9 @@ class ServeEngine:
         if swap is None:
             return
         params, done = swap
+        if self._device is not None:
+            import jax
+            params = jax.device_put(params, self._device)
         self._params = params
         with self._lock:
             self._counters["param_swaps"] += 1
@@ -816,13 +857,16 @@ class ServeEngine:
         nothing compiles mid-serving. Prompt-length prefill shapes still
         compile on first sight — warm those by submitting representative
         prompts."""
+        with self._on_device():
+            self._warmup()
+        return self
+
+    def _warmup(self) -> None:
         import jax
         import jax.numpy as jnp
         from repro.models import transformer
+        state = self._init_state()
         if self._has_paged:
-            state = transformer.init_decode_state(
-                self._cfg, self._ns, self._Lp, page_size=self._ps,
-                num_pages=self._P + 1)
             # Compact engines pick a window width per window (the
             # power-of-two ladder up to num_slots), so warm the whole
             # width x K grid — a mid-run width change must not stall
@@ -847,8 +891,6 @@ class ServeEngine:
                     jax.block_until_ready(out)
                     k = min(k * 2, self._sync) if k < self._sync else k * 2
         else:
-            state = transformer.init_decode_state(self._cfg, self._ns,
-                                                  self._Lp)
             toks = jnp.zeros((self._ns, 1), jnp.int32)
             t = jnp.zeros((self._ns,), jnp.int32)
             key = None if self._key is None else jax.random.key(0)
@@ -863,20 +905,45 @@ class ServeEngine:
             chunk = jnp.zeros((1, self._chunk), jnp.int32)
             logits, _ = self._extend(self._params, st1, chunk, jnp.int32(0))
             jax.block_until_ready(logits)
-        return self
 
-    def start(self) -> "ServeEngine":
+    def start(self, warmup: bool = False) -> "ServeEngine":
+        """Start the daemon decode loop. With ``warmup`` the loop thread
+        first compiles the decode ladder (``warmup()``; the seconds land
+        in the ``engine.warmup_s`` histogram) while submitted requests
+        queue. ``ready`` is set once the loop serves."""
         if self._thread is None:
-            self._thread = threading.Thread(target=self._loop, daemon=True,
+            self._thread = threading.Thread(target=self._loop,
+                                            args=(warmup,), daemon=True,
                                             name="serve-engine")
             self._thread.start()
         return self
 
-    def _loop(self) -> None:
-        while not self._stop.is_set():
-            if self.step() == 0:
-                self._wake.wait(timeout=0.05)
-                self._wake.clear()
+    def _loop(self, warmup: bool = False) -> None:
+        with self._on_device():
+            try:
+                if warmup:
+                    t0 = time.perf_counter()
+                    self._warmup()
+                    telemetry.metrics().histogram("engine.warmup_s").record(
+                        time.perf_counter() - t0)
+                self.ready.set()
+                while not self._stop.is_set():
+                    if self.step() == 0:
+                        self._wake.wait(timeout=0.05)
+                        self._wake.clear()
+            except Exception as exc:                        # noqa: BLE001
+                # A warm-up or step that raises (a compile error, a device
+                # fault) leaves the slot state unknown: stop serving and
+                # hand the real error to every waiting caller now, instead
+                # of letting them run out their timeouts.
+                telemetry.get_logger(self._node).exception(
+                    "engine step failed; engine stopped", error=repr(exc))
+                with self._lock:
+                    self._closed = True
+                    self._error = exc
+                self._fail_all(exc)
+            finally:
+                self.ready.set()
 
     def stop(self) -> None:
         """Stop the loop and fail anything still queued or in flight."""
@@ -887,11 +954,15 @@ class ServeEngine:
         if self._thread is not None:
             self._thread.join(timeout=30)
             self._thread = None
+        self._fail_all(RuntimeError("engine stopped"))
+
+    def _fail_all(self, err: BaseException) -> None:
+        """Fail every queued, pending and in-flight request with ``err``
+        and release their slots and pages (the engine is closed)."""
         with self._lock:
             swap, self._pending_swap = self._pending_swap, None
         if swap is not None:
             swap[1].set()       # unblock a swap_params caller mid-stop
-        err = RuntimeError("engine stopped")
         while True:
             try:
                 req = self._queue.get_nowait()

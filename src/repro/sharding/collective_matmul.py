@@ -66,8 +66,7 @@ def collective_matmul(x: jax.Array, w: jax.Array, mesh: Mesh,
     W column-sharded — without a blocking X all-gather."""
     dp = tuple(a for a in dp_axes if a in mesh.axis_names)
     dp_spec = dp if len(dp) > 1 else (dp[0] if dp else None)
-    from repro.sharding.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_matmul, tp_axis, mesh.shape[tp_axis]),
         mesh=mesh,
         in_specs=(P(dp_spec, None, tp_axis), P(None, tp_axis)),

@@ -65,8 +65,7 @@ def compress_reduce_pod(grads, error_state, mesh: Mesh,
             return r, new_err
 
         spec = P()  # per-pod replicated view of the (already FSDP'd) grad
-        from repro.sharding.compat import shard_map
-        fn = shard_map(inner, mesh=mesh,
+        fn = jax.shard_map(inner, mesh=mesh,
                        in_specs=(spec, spec), out_specs=(spec, spec),
                        check_vma=False)
         return fn(g, e)

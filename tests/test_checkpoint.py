@@ -126,8 +126,8 @@ def test_elastic_reshard_roundtrip(tmp_path):
             jax.random.normal(jax.random.key(4), (2, 4, 8))}}}}}
     d = str(tmp_path / "ck")
     checkpoint.save(tree, d)
-    from repro.sharding.compat import make_mesh
-    mesh = make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     out = elastic.restore_elastic(d, like=tree, new_mesh=mesh)
     leaf = out["blocks"]["0"]["mlp"]["w_up"]["kernel"]
     np.testing.assert_array_equal(

@@ -16,9 +16,8 @@ _PRELUDE = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.sharding.compat import make_mesh
-mesh = make_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 """
 
 
@@ -98,7 +97,8 @@ def test_grad_compression_cross_pod():
     out = _run("""
     import os
     from repro.train.grad_compression import compress_reduce_pod
-    mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                          axis_types=(AxisType.Auto,) * 3)
     g = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8) / 64.0}
     # replicate across pods with different values -> psum averages them
     def make(v):

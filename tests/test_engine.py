@@ -1,6 +1,11 @@
 """ServeEngine invariants: slotted-KV-cache admission, retirement, and
 per-request delivery semantics (continuous batching)."""
 
+import os
+import subprocess
+import sys
+import time
+
 import numpy as np
 import pytest
 
@@ -172,6 +177,96 @@ def test_background_loop_serves(params):
         for p, f in zip(prompts, futs):
             assert f.result(timeout=120).shape == (len(p) + MAX_NEW,)
     assert engine.stats()["retired"] == 3
+
+
+@pytest.mark.parametrize("warmup", [False, True])
+def test_decode_failure_fails_waiters_fast(params, warmup):
+    """A decode window that raises in the daemon loop (a compile error, a
+    device fault) — while serving, or in the loop's warm-up — fails every
+    in-flight and queued request with that exception at once, not after
+    the caller's timeout, and stops the engine."""
+    engine = ServeEngine(CFG, params, num_slots=1, context_len=L,
+                         max_new=MAX_NEW)
+    boom = RuntimeError("injected decode failure")
+
+    def broken_window(steps):
+        def run(*args, **kwargs):
+            raise boom
+        return run
+
+    engine._fused = broken_window
+    prompts = _prompts([5, 6, 7], seed=11)
+    futs = [engine.submit(p) for p in prompts]   # queued before the loop
+    t0 = time.monotonic()
+    engine.start(warmup=warmup)
+    for f in futs:
+        with pytest.raises(RuntimeError, match="injected decode failure"):
+            f.result(timeout=30)
+    assert time.monotonic() - t0 < 30
+    assert not engine.alive
+    with pytest.raises(RuntimeError, match="injected decode failure"):
+        engine.submit(prompts[0]).result(timeout=5)
+    assert engine.stats()["free_slots"] == 1
+    engine.stop()
+
+
+def test_page_size_the_kernel_cannot_tile_is_refused_on_tpu(params,
+                                                            monkeypatch):
+    """On TPU, a page size whose K/V blocks overflow the paged kernel's
+    VMEM is refused when the engine is built, not at the first decode."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="page_size 131072 cannot be tiled"):
+        ServeEngine(CFG, params, num_slots=2, context_len=L,
+                    max_new=MAX_NEW, page_size=131072)
+    # The dense path never runs the kernel, and a tileable page is fine.
+    ServeEngine(CFG, params, num_slots=2, context_len=L, max_new=MAX_NEW,
+                page_size=131072, num_pages=2, decode_impl="dense")
+    ServeEngine(CFG, params, num_slots=2, context_len=L, max_new=MAX_NEW,
+                page_size=8)
+
+
+_ON_DEVICE = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import jax, jax.numpy as jnp, numpy as np
+from repro import configs
+from repro.models import transformer
+from repro.serve import decode as serve_lib
+from repro.serve.engine import ServeEngine
+cfg = configs.get_reduced("qwen2-1.5b")
+d0, d1 = jax.devices()
+params = transformer.init_params(cfg, jax.random.key(0))
+prompt = np.arange(3, 9, dtype=np.int32)
+solo = np.asarray(serve_lib.generate(cfg, params, jnp.asarray(prompt[None]),
+                                     max_new=4, context_len=24))[0]
+for page_size in (None, 8):
+    eng = ServeEngine(cfg, params, num_slots=2, context_len=24, max_new=4,
+                      page_size=page_size, device=d1)
+    def on_d1():
+        leaves = jax.tree.leaves((eng._params, eng._state, eng._tokens_dev,
+                                  eng._t_dev))
+        return all(x.devices() == {d1} for x in leaves)
+    assert on_d1(), "engine state not on its device"
+    with eng.warmup():
+        out = eng.submit(prompt).result(timeout=120)
+    assert on_d1(), "decode moved state off the device"
+    np.testing.assert_array_equal(out, solo)
+print("OK")
+"""
+
+
+def test_engine_lives_on_its_device():
+    """``device=`` pins params, decode state and the device-resident feed
+    state to one device (one replica per chip), through warmup and
+    serving, on both KV layouts — and serves the same tokens."""
+    env = {k: os.environ[k] for k in ("PATH", "HOME", "TMPDIR")
+           if k in os.environ}
+    env.update(PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", _ON_DEVICE],
+                          capture_output=True, text=True, timeout=600,
+                          env=env)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert proc.stdout.strip().endswith("OK")
 
 
 # -- roofline decode path: fused windows, kernel dispatch, chunked prefill ----

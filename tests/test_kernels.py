@@ -71,6 +71,8 @@ DECODE_CASES = [
     (1, 8, 1, 128, 1024),
     (3, 4, 4, 32, 512),
     (1, 16, 8, 128, 2048),
+    (8, 4, 2, 64, 520),       # engine-sized batch, L off the lane grid
+    (2, 4, 2, 64, 40),        # L shorter than one lane block
 ]
 
 
@@ -110,22 +112,39 @@ def test_decode_attention_dispatch_paths_agree():
                                   np.zeros((H, dh), np.float32))
 
 
-def test_decode_dispatch_resolution(monkeypatch):
-    """Dispatch priority: explicit impl > REPRO_FORCE_REF > interpret
-    flag > backend default (ref everywhere but TPU)."""
-    monkeypatch.delenv("REPRO_FORCE_REF", raising=False)
+def test_decode_dispatch_resolution():
+    """Dispatch priority: explicit impl > interpret flag > backend default
+    (ref everywhere but TPU)."""
     assert ops.resolve_decode_impl(impl="ref") == "ref"
+    assert ops.resolve_decode_impl(impl="ref", interpret=True) == "ref"
     assert ops.resolve_decode_impl(impl="pallas") == "pallas"
+    assert ops.resolve_decode_impl(impl="pallas", interpret=False) == "pallas"
     assert ops.resolve_decode_impl(interpret=True) == "pallas"
+    assert ops.resolve_decode_impl(interpret=False) == "pallas"
     default = ops.resolve_decode_impl()
     assert default == ("pallas" if jax.default_backend() == "tpu"
                        else "ref")
-    monkeypatch.setenv("REPRO_FORCE_REF", "1")
-    assert ops.resolve_decode_impl() == "ref"
-    assert ops.resolve_decode_impl(interpret=True) == "ref"
-    assert ops.resolve_decode_impl(impl="pallas") == "pallas"  # pin wins
     with pytest.raises(ValueError):
         ops.resolve_decode_impl(impl="dense")
+
+
+@pytest.mark.parametrize("L,want,block", [
+    (40, 512, 40), (512, 512, 512), (520, 512, 384), (1088, 512, 384),
+    (2048, 512, 512), (1032, 512, 384), (256, 64, 128)])
+def test_decode_block_is_lane_aligned(L, want, block):
+    """The flat kernel's block covers L with the least padding, and is a
+    multiple of the 128-lane width unless one block holds all of L."""
+    got = ops._block_l(L, want)
+    assert got == block
+    assert got == L or got % ops.LANE == 0
+
+
+def test_paged_page_size_rule():
+    """Pages whose K/V blocks (double-buffered) overflow the kernel's
+    VMEM budget are refused; every smaller page tiles."""
+    for ps in (1, 8, 16, 100, 1024, 15360):
+        assert ops.paged_page_size_ok(ps, head_dim=128)
+    assert not ops.paged_page_size_ok(16384, head_dim=128)
 
 
 def test_decode_attention_ring_semantics_match_model():

@@ -144,3 +144,81 @@ def test_resources_for_unknown_group_rejected():
     p.add_node(lp.PyNode(Waits))
     with pytest.raises(ValueError, match="unknown groups"):
         lp.ThreadLauncher().launch(p, resources={"nope": {}})
+
+
+# ---- devices: one per mesh worker, never a forked child on a TPU host ------
+
+_DEVICE_HANDOUT = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+from repro import core as lp
+
+got = {}
+
+
+class Worker:
+    def __init__(self, tag, mesh=None):
+        got[tag] = [d.id for d in mesh.devices.flat]
+
+    def run(self):
+        if len(got) == 4:
+            lp.stop_program()
+
+
+p = lp.Program("devices")
+with p.group("solo"):
+    for i in range(3):
+        p.add_node(lp.MeshWorkerNode(Worker, f"w{i}"))
+with p.group("pair"):
+    p.add_node(lp.MeshWorkerNode(Worker, "pair"))
+lp.launch_and_wait(p, resources={"pair": {"mesh": (2,), "axes": ("data",)}},
+                   timeout_s=60)
+print(sorted(got.items()))
+"""
+
+
+def test_mesh_workers_get_devices_in_launch_order():
+    """The thread launcher hands each mesh worker the next devices: three
+    one-device workers get a device each, and a two-device worker takes
+    the next two, wrapping around past the last device."""
+    import subprocess
+    import sys
+    env = {k: os.environ[k] for k in ("PATH", "HOME", "TMPDIR")
+           if k in os.environ}
+    env.update(PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", _DEVICE_HANDOUT],
+                          capture_output=True, text=True, timeout=300,
+                          env=env)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert proc.stdout.strip().splitlines()[-1] == (
+        "[('pair', [3, 0]), ('w0', [0]), ('w1', [1]), ('w2', [2])]")
+
+
+class _Chipless:
+    def __init__(self, mesh=None):
+        pass
+
+
+def test_process_launcher_refuses_mesh_worker_on_tpu_host(monkeypatch):
+    """A forked child cannot take a chip its parent holds: on a TPU host
+    the process launcher refuses a mesh worker outright, naming the
+    launcher that works."""
+    from repro.core.launchers import process
+    monkeypatch.setattr(process, "_tpu_host", lambda: True)
+    p = lp.Program("tpu-mesh")
+    with p.group("learner"):
+        p.add_node(lp.MeshWorkerNode(_Chipless))
+    launcher = lp.ProcessLauncher()
+    try:
+        with pytest.raises(RuntimeError, match="ThreadLauncher"):
+            launcher.launch(p)
+    finally:
+        launcher.terminate()
+
+
+def test_tpu_host_detection_respects_platform_pin():
+    """With JAX pinned to the CPU (as under test) no chip would be taken,
+    so process-launched mesh workers stay allowed."""
+    from repro.core.launchers import process
+    assert os.environ.get("JAX_PLATFORMS") == "cpu"
+    assert process._tpu_host() is False

@@ -87,6 +87,21 @@ def test_validate_rejects_foreign_handles():
         p2.validate()
 
 
+def test_handle_minted_after_add_node_belongs_to_its_node():
+    """A handle taken from a node of a program that a builder function
+    returned still wires a new consumer to that node."""
+    p = lp.Program("t")
+    with p.group("svc"):
+        p.add_node(lp.CourierNode(Svc))
+    node = p.groups["svc"].nodes[0]
+    h = node.create_handle()
+    consumer = lp.CourierNode(Other, h)
+    p.add_node(consumer)
+    p.validate()
+    assert p.owner_of(h) is node
+    assert p.edges() == [(consumer, node)]
+
+
 def test_dryrun_launcher_reports_topology():
     p = lp.Program("t")
     with p.group("producer"):

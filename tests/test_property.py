@@ -157,8 +157,8 @@ def test_fit_spec_always_divisible(shape):
     from repro.sharding.rules import fit_spec
     if len(jax.devices()) < 1:
         pytest.skip("no devices")
-    from repro.sharding.compat import make_mesh
-    mesh = make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     spec = fit_spec(mesh, shape, [("data", "model")] * len(shape))
     assert isinstance(spec, PartitionSpec)
     # every sharded dim is divisible by the axis product
