@@ -205,6 +205,7 @@ def check_decode_windows(cfg, context_len=PROMPT_LEN + MAX_NEW,
     (``attn_impl="auto"``), must contain the Pallas kernel."""
     import jax
     import jax.numpy as jnp
+    from repro.kernels import ops
     from repro.models import transformer
     from repro.serve import decode as serve_lib
 
@@ -212,7 +213,8 @@ def check_decode_windows(cfg, context_len=PROMPT_LEN + MAX_NEW,
     toks = jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32)
     t = jax.ShapeDtypeStruct((SLOTS,), jnp.int32)
     n = -(-context_len // page_size)
-    flat = transformer.decode_state_spec(cfg, SLOTS, context_len)
+    flat = transformer.decode_state_spec(cfg, SLOTS,
+                                         ops.decode_cache_len(context_len))
     pool = transformer.decode_state_spec(cfg, SLOTS, n * page_size,
                                          page_size=page_size,
                                          num_pages=SLOTS * n + 1)

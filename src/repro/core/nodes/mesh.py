@@ -18,7 +18,8 @@ per host; the single-machine launchers build the mesh from local devices,
 handing ``jax.devices()`` out in launch order: each mesh worker takes the
 next ``prod(mesh)`` devices (``device_offset``), so N one-device workers
 on an N-chip host get a chip each. Workers that outnumber the devices
-wrap around and share them.
+are refused on an accelerator host; on the CPU (tests) they wrap around
+and share them.
 """
 
 from __future__ import annotations
@@ -58,6 +59,14 @@ class _MeshExecutable(Executable):
                 f"host platform has {len(devices)} "
                 "(set XLA_FLAGS=--xla_force_host_platform_device_count=N "
                 "before jax initializes, or shrink the mesh resource)")
+        end = self.device_offset + n_need
+        if end > len(devices) and jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"mesh worker {self.name!r} would take devices "
+                f"{self.device_offset}..{end - 1}, but the host has "
+                f"{len(devices)} {jax.default_backend()} devices: mesh "
+                "workers would share a chip (launch fewer workers or "
+                "smaller meshes)")
         picked = [devices[(self.device_offset + i) % len(devices)]
                   for i in range(n_need)]
         return jax.make_mesh(

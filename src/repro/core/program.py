@@ -29,9 +29,7 @@ class Program:
         self.name = name
         self.groups: dict[str, ResourceGroup] = {}
         self._current_group: Optional[str] = None
-        # Graph bookkeeping: node -> handle (or None), and handle -> owner node.
         self.nodes: list[Node] = []
-        self._handle_owner: dict[int, Node] = {}  # id(handle) -> node
 
     # ---- resource groups ---------------------------------------------------
     @contextlib.contextmanager
@@ -66,14 +64,7 @@ class Program:
         node.name = f"{group_name}/{node.name}_{len(self.nodes)}"
         self.nodes.append(node)
 
-        handle = node.create_handle()
-        if handle is not None:
-            self._handle_owner[id(handle)] = node
-        # Adopt handles minted before add_node (e.g. handles of nodes
-        # wrapped in a ColocationNode, created to wire them to each other).
-        for h in getattr(node, "_created_handles", ()):
-            self._handle_owner.setdefault(id(h), node)
-        return handle
+        return node.create_handle()
 
     # ---- introspection -------------------------------------------------------
     def edges(self) -> list[tuple[Node, Node]]:
@@ -87,15 +78,12 @@ class Program:
         return out
 
     def owner_of(self, handle: Handle) -> Optional[Node]:
-        owner = self._handle_owner.get(id(handle))
-        if owner is None:
-            # A handle minted from a node after add_node (e.g. a driver
-            # wired into a program that a builder function returned).
-            for node in self.nodes:
-                if any(h is handle for h in node._created_handles):
-                    self._handle_owner[id(handle)] = owner = node
-                    break
-        return owner
+        """The node that minted ``handle`` — before or after add_node, or
+        inside a ColocationNode, which adopts its members' handles."""
+        for node in self.nodes:
+            if any(h is handle for h in node._created_handles):
+                return node
+        return None
 
     def validate(self) -> None:
         """Structural checks run by launchers before anything starts."""
@@ -114,9 +102,9 @@ class Program:
         for gname, group in self.groups.items():
             lines.append(f"  group {gname}:")
             for node in group.nodes:
-                deps = [self._handle_owner[id(h)].name
-                        for h in node.input_handles
-                        if id(h) in self._handle_owner]
+                deps = [owner.name for owner in map(self.owner_of,
+                                                    node.input_handles)
+                        if owner is not None]
                 suffix = f" <- {deps}" if deps else ""
                 lines.append(f"    {node.name} [{type(node).__name__}]{suffix}")
         return "\n".join(lines)

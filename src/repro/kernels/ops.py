@@ -59,6 +59,18 @@ def _block_l(L: int, want: int) -> int:
     return _cdiv(_cdiv(L, nblk), LANE) * LANE
 
 
+DECODE_BLOCK_L = 512    # decode_attention's default block along the cache
+
+
+def decode_cache_len(L: int, block_l: int = DECODE_BLOCK_L) -> int:
+    """The smallest cache length >= ``L`` that the flat kernel tiles
+    without padding. A cache allocated at this length (slots past the
+    context are never valid) saves ``decode_attention`` a padded copy of
+    K and V on every call."""
+    bl = _block_l(L, block_l)
+    return _cdiv(L, bl) * bl
+
+
 # Mosaic's default per-kernel VMEM budget on TPU v5e.
 SCOPED_VMEM_BYTES = 16 * 2 ** 20
 
@@ -104,7 +116,7 @@ def _decode_pallas(q, k, v, valid, block_l: int, interpret: bool):
 _decode_ref = jax.jit(_ref.decode_attention)
 
 
-def decode_attention(q, k, v, valid, block_l: int = 512,
+def decode_attention(q, k, v, valid, block_l: int = DECODE_BLOCK_L,
                      interpret: Optional[bool] = None,
                      impl: Optional[str] = None):
     """Backend-dispatched single-token attention (see resolve_decode_impl).
@@ -113,6 +125,8 @@ def decode_attention(q, k, v, valid, block_l: int = 512,
     share one contract, including all-invalid rows -> zeros. Any ``L`` is
     accepted: the kernel path pads the cache to a whole number of
     lane-aligned blocks (pad slots are invalid, so they never count).
+    That pad copies K and V, so callers on a hot path allocate their
+    cache at ``decode_cache_len(L)`` (the serve engine does).
     """
     if resolve_decode_impl(impl, interpret) == "ref":
         return _decode_ref(q, k, v, valid)

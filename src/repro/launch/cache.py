@@ -1,10 +1,11 @@
 """JAX's persistent compilation cache, configured once per entry point.
 
 A cold compile of a full-width model's decode window takes tens of
-seconds; the persistent cache lets a later run load it instead. The
-cache's key includes its directory, so the directory never moves between
-runs: ``$JAX_COMPILATION_CACHE_DIR`` when the environment sets it (JAX
-reads that variable itself), else ``<checkout>/.jax_cache``.
+seconds; the persistent cache lets a later run load it instead. A later
+run finds the entries only where an earlier one put them, so the
+directory never moves between runs (no temp, pid- or time-derived path):
+``$JAX_COMPILATION_CACHE_DIR`` when the environment sets it (JAX reads
+that variable itself), else ``<checkout>/.jax_cache``.
 
     from repro.launch.cache import enable_compile_cache
     enable_compile_cache()      # before the first compile

@@ -181,7 +181,10 @@ class EngineServer:
             if v is None:
                 raise ValueError(f"model store {store_dir!r} has no "
                                  "published versions")
-            params = self._store.load_version(int(v), like=params)
+            # The store returns host arrays; the engine lives where its
+            # params are.
+            params = jax.device_put(
+                self._store.load_version(int(v), like=params), device)
             self._version = int(v)
         self._engine = ServeEngine(
             model_cfg, params, num_slots=num_slots,
@@ -189,7 +192,7 @@ class EngineServer:
             max_new=max_new, eos_id=eos_id, sync_every=sync_every,
             decode_impl=decode_impl, top_k=top_k,
             prefill_chunk=prefill_chunk, page_size=page_size,
-            num_pages=num_pages, prefix_cache=prefix_cache, device=device)
+            num_pages=num_pages, prefix_cache=prefix_cache)
         self._engine.start(warmup=True)
         self._heartbeater = None
         if registry is not None:

@@ -148,9 +148,10 @@ class ServeEngine:
     daemon loop, every queued and in-flight request fails with that
     exception and the engine stops (``alive`` turns False).
 
-    ``device`` pins the replica to one device: params, the decode state
-    and the device-resident feed tokens/positions live there, and the
-    engine thread allocates there (default: JAX's default device).
+    The engine lives on the device that holds ``params`` (when they sit
+    on one device): the decode state and the device-resident feed
+    tokens/positions are allocated there, the engine thread allocates
+    there, and swapped-in weights are moved there.
     """
 
     def __init__(self, cfg: ModelConfig, params, *, num_slots: int = 8,
@@ -161,7 +162,7 @@ class ServeEngine:
                  prefill_chunk: Optional[int] = None,
                  page_size: Optional[int] = None,
                  num_pages: Optional[int] = None,
-                 prefix_cache: bool = True, device=None):
+                 prefix_cache: bool = True):
         import jax
         import jax.numpy as jnp
         from repro.kernels import ops as kernel_ops
@@ -189,10 +190,10 @@ class ServeEngine:
                 "double-buffered K/V page blocks overflow the kernel's "
                 f"{kernel_ops.SCOPED_VMEM_BYTES >> 20} MiB VMEM budget; "
                 "use a smaller page_size")
-        self._device = device
+        leaf = jax.tree.leaves(params)[0]
+        devices = leaf.devices() if isinstance(leaf, jax.Array) else set()
+        self._device = next(iter(devices)) if len(devices) == 1 else None
         self._cfg = cfg
-        if device is not None:
-            params = jax.device_put(params, device)
         self._params = params
         self._ns = num_slots
         self._L = context_len
@@ -205,10 +206,12 @@ class ServeEngine:
         self._key = jax.random.key(seed) if temperature else None
 
         kinds = set(cfg.pattern) | set(cfg.remainder)
-        # Paged KV pool geometry. The internal ring modulus is context_len
-        # rounded UP to whole pages (L_pad): submit() still rejects
-        # prompt+max_new > context_len, so positions never wrap for any
-        # modulus >= context_len and the ring-validity math is unchanged.
+        # The internal ring modulus (_Lp) is context_len rounded UP: to
+        # whole pages when paged, else to a length the flat flash-decode
+        # kernel tiles without padding the cache on every call. submit()
+        # still rejects prompt+max_new > context_len, so positions never
+        # wrap for any modulus >= context_len and the ring-validity math
+        # is unchanged.
         # Stacks with no full-context ATTN layer (pure windowed/recurrent)
         # have nothing to page — they accept the knobs but run the flat
         # per-row layout with an unlimited "pool".
@@ -226,7 +229,8 @@ class ServeEngine:
                 pass
         else:
             self._ps = 0
-            self._Lp = context_len
+            self._Lp = (context_len if decode_impl == "dense"
+                        else kernel_ops.decode_cache_len(context_len))
             self._has_paged = False
         # Compact windows: with every cache leaf behind the page table
         # (attention-only stack), the fused window's batch width is a free

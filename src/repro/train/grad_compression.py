@@ -66,8 +66,8 @@ def compress_reduce_pod(grads, error_state, mesh: Mesh,
 
         spec = P()  # per-pod replicated view of the (already FSDP'd) grad
         fn = jax.shard_map(inner, mesh=mesh,
-                       in_specs=(spec, spec), out_specs=(spec, spec),
-                       check_vma=False)
+                           in_specs=(spec, spec), out_specs=(spec, spec),
+                           check_vma=False)
         return fn(g, e)
 
     if error_state is None:

@@ -225,6 +225,34 @@ def test_page_size_the_kernel_cannot_tile_is_refused_on_tpu(params,
                 page_size=8)
 
 
+def test_flat_ring_is_allocated_at_a_length_the_kernel_tiles(params):
+    """The flat KV rings are allocated at ``decode_cache_len(context_len)``,
+    so the flash-decode wrapper never pads (copies) the cache on a decode
+    step; the dense path keeps ``context_len``. Requests are still held to
+    ``context_len`` and decode as they would alone."""
+    from repro.kernels import ops
+
+    def shapes(tree):
+        return [x.shape for x in jax.tree.leaves(tree)]
+
+    def spec(length):
+        return shapes(transformer.decode_state_spec(CFG, 2, length))
+
+    ctx = 1032
+    flash = ServeEngine(CFG, params, num_slots=2, context_len=ctx,
+                        max_new=MAX_NEW, decode_impl="flash")
+    assert shapes(flash._state) == spec(ops.decode_cache_len(ctx)) != spec(ctx)
+    dense = ServeEngine(CFG, params, num_slots=2, context_len=ctx,
+                        max_new=MAX_NEW, decode_impl="dense")
+    assert shapes(dense._state) == spec(ctx)
+    with pytest.raises(ValueError, match="exceeds"):
+        flash.submit(np.zeros(ctx - MAX_NEW + 1, np.int32)).result(timeout=5)
+    prompt = _prompts([6])[0]
+    fut = flash.submit(prompt)
+    _run(flash, [fut])
+    np.testing.assert_array_equal(fut.result(), _solo(params, prompt))
+
+
 _ON_DEVICE = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
@@ -240,8 +268,8 @@ prompt = np.arange(3, 9, dtype=np.int32)
 solo = np.asarray(serve_lib.generate(cfg, params, jnp.asarray(prompt[None]),
                                      max_new=4, context_len=24))[0]
 for page_size in (None, 8):
-    eng = ServeEngine(cfg, params, num_slots=2, context_len=24, max_new=4,
-                      page_size=page_size, device=d1)
+    eng = ServeEngine(cfg, jax.device_put(params, d1), num_slots=2,
+                      context_len=24, max_new=4, page_size=page_size)
     def on_d1():
         leaves = jax.tree.leaves((eng._params, eng._state, eng._tokens_dev,
                                   eng._t_dev))
@@ -249,16 +277,17 @@ for page_size in (None, 8):
     assert on_d1(), "engine state not on its device"
     with eng.warmup():
         out = eng.submit(prompt).result(timeout=120)
-    assert on_d1(), "decode moved state off the device"
+        eng.swap_params(jax.device_put(params, d0))
+    assert on_d1(), "decode or a weight swap moved state off the device"
     np.testing.assert_array_equal(out, solo)
 print("OK")
 """
 
 
 def test_engine_lives_on_its_device():
-    """``device=`` pins params, decode state and the device-resident feed
-    state to one device (one replica per chip), through warmup and
-    serving, on both KV layouts — and serves the same tokens."""
+    """Params on one device pin the decode state and the device-resident
+    feed state there too (one replica per chip), through warmup and
+    serving, on both KV layouts — and serve the same tokens."""
     env = {k: os.environ[k] for k in ("PATH", "HOME", "TMPDIR")
            if k in os.environ}
     env.update(PYTHONPATH="src", JAX_PLATFORMS="cpu")

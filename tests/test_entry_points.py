@@ -44,7 +44,8 @@ def test_compile_cache_uses_env_dir_only(tmp_path):
 
 def test_compile_cache_defaults_to_checkout_dir():
     """Without the variable the cache is <checkout>/.jax_cache — a fixed
-    path (the directory is part of the cache key), ignored by git."""
+    path, so a later run finds what an earlier one cached — ignored by
+    git."""
     returned, configured = _run("config-only")
     expect = os.path.join(os.getcwd(), ".jax_cache")
     assert returned == configured == str(cache.CHECKOUT_CACHE_DIR) == expect

@@ -139,6 +139,19 @@ def test_decode_block_is_lane_aligned(L, want, block):
     assert got == L or got % ops.LANE == 0
 
 
+@pytest.mark.parametrize("L,cache_len", [
+    (40, 40), (512, 512), (520, 768), (1032, 1152), (1088, 1152),
+    (2048, 2048), (2049, 2560)])
+def test_decode_cache_len_needs_no_pad(L, cache_len):
+    """A cache allocated at decode_cache_len(L) holds L, is a whole number
+    of the kernel's blocks (the wrapper pads nothing), and is a fixed
+    point."""
+    got = ops.decode_cache_len(L)
+    assert got == cache_len
+    assert got % ops._block_l(got, ops.DECODE_BLOCK_L) == 0
+    assert ops.decode_cache_len(got) == got
+
+
 def test_paged_page_size_rule():
     """Pages whose K/V blocks (double-buffered) overflow the kernel's
     VMEM budget are refused; every smaller page tiles."""

@@ -194,6 +194,22 @@ def test_mesh_workers_get_devices_in_launch_order():
         "[('pair', [3, 0]), ('w0', [0]), ('w1', [1]), ('w2', [2])]")
 
 
+def test_mesh_workers_past_the_last_chip_are_refused(monkeypatch):
+    """On an accelerator host a mesh worker that would wrap around onto a
+    device another worker holds is refused, not silently doubled up."""
+    import jax
+    from repro.core.nodes.mesh import _MeshExecutable
+    from repro.core.addressing import Address
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n = len(jax.devices())
+    ex = _MeshExecutable("w", object, (), {}, Address("w"), (1,), ("data",))
+    ex.device_offset = n - 1
+    assert ex._build_mesh().devices.flat[0] == jax.devices()[n - 1]
+    ex.device_offset = n
+    with pytest.raises(RuntimeError, match="share a chip"):
+        ex._build_mesh()
+
+
 class _Chipless:
     def __init__(self, mesh=None):
         pass

@@ -88,8 +88,8 @@ def test_validate_rejects_foreign_handles():
 
 
 def test_handle_minted_after_add_node_belongs_to_its_node():
-    """A handle taken from a node of a program that a builder function
-    returned still wires a new consumer to that node."""
+    """A handle taken from a node of an already built program still wires
+    a new consumer to that node."""
     p = lp.Program("t")
     with p.group("svc"):
         p.add_node(lp.CourierNode(Svc))

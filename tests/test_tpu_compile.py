@@ -1,7 +1,8 @@
 """Compile the serving path's Pallas kernels for a described TPU v5e.
 
-No chip is needed: the TPU compiler is installed here and compiles for a
-topology that is described, not attached. This catches what interpret
+No chip is needed: where the TPU compiler (libtpu) is installed, it
+compiles for a topology that is described, not attached; where it is not
+(a CPU-only jax install), the tests skip. This catches what interpret
 mode cannot — block shapes the chip cannot tile, kernels that overflow
 VMEM — at qwen2-1.5b's head widths (12 query heads, 2 KV heads, head
 dim 128, bf16 cache) and the engine's batch of 8 slots.
@@ -38,14 +39,16 @@ def one_chip():
     compilation_cache.reset_cache()
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - any failure means no topology
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", prev_cache)
-    if prev_log is None:
-        os.environ.pop("TPU_LOG_DIR", None)
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - no TPU compiler installed
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev_cache)
+        if prev_log is None:
+            os.environ.pop("TPU_LOG_DIR", None)
 
 
 def _spec(sharding, shape, dtype):
