@@ -37,10 +37,20 @@ in different same-host processes align on one timeline; durations come
 from ``perf_counter`` deltas. Cross-host alignment is out of scope (the
 shm fabric is same-host anyway).
 
-Overhead budget: an unsampled request pays one contextvar read per
-courier hop (~100ns) and nothing else; a sampled request pays ~2us per
-span (dict build + deque append). The serve benchmark gates the
-telemetry-on arm at <= 1.03x the off arm at the mixed scenario.
+**Phases** — :func:`phase` puts a named stretch of a thread's time into
+the ``jax.profiler`` trace (a ``TraceAnnotation``), on the same clock as
+the device's operations, and keeps its own start and duration for callers
+that also record a span or a counter from it. Phases are not sampled: an
+annotation records only while a profiler session runs. Sampled
+:func:`span` records open a phase of the same name, so they show in a
+profiler trace too.
+
+Overhead: an unsampled request pays one contextvar read per courier hop
+(~100ns) and nothing else; a sampled request pays ~2us per span (dict
+build + deque append); a phase costs 1.2us with no profiler session
+running and 4.5us with one (on a TPU v5e host, ``scripts/phase_cost.py``).
+The serve engine's phases are read by the benchmark's trace metrics
+(``bench/metrics``); see ``TELEMETRY.md``.
 """
 
 from __future__ import annotations
@@ -420,25 +430,13 @@ class SpanBuffer:
 _spans = SpanBuffer()
 _events = SpanBuffer(maxlen=2048)
 
-# Fallback node attribution for spans recorded on threads that never got a
-# WorkerContext (engine decode loops, dispatcher threads). Services capture
-# telemetry.node_name() at construction and pass it explicitly when they
-# can; this keeps the default better than "standalone".
-_default_node: Optional[str] = None
-
-
-def set_default_node(name: str) -> None:
-    global _default_node
-    _default_node = name
-
-
 def node_name() -> str:
     """Best-effort name of the node this thread serves."""
     from repro.core.nodes.base import _context_local
     ctx = getattr(_context_local, "ctx", None)
     if ctx is not None and ctx.node_name != "standalone":
         return ctx.node_name
-    return _default_node or f"pid-{os.getpid()}"
+    return f"pid-{os.getpid()}"
 
 
 def record_span(name: str, ctx: TraceContext, start_wall: float,
@@ -454,27 +452,62 @@ def record_span(name: str, ctx: TraceContext, start_wall: float,
     return sid
 
 
+_annotation: Optional[type] = None
+
+
+class phase:
+    """One named stretch of this thread's time, put into the
+    ``jax.profiler`` trace as a ``TraceAnnotation`` of the same name (on
+    the device trace's clock) and timed here: ``wall`` is its start on
+    ``time.time()``, ``dur`` its seconds on ``perf_counter``, set when the
+    block exits. Callers that also record a span or a rate from the same
+    stretch take both from the phase. Not sampled: the annotation records
+    only while a profiler session runs, and costs about a microsecond
+    when none does."""
+
+    __slots__ = ("name", "wall", "dur", "_t0", "_ann")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.wall = 0.0
+        self.dur = 0.0
+
+    def __enter__(self) -> "phase":
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        self.wall = time.time()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc_info) -> None:
+        self.dur = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc_info)
+
+
 @contextlib.contextmanager
 def span(name: str, ctx: Optional[TraceContext] = None, **attrs):
-    """Timed span context manager. No-op (one contextvar read) when the
-    request is unsampled. Within the block the current context points at
-    this span, so nested spans — including remote ones, via the envelope —
-    parent correctly."""
+    """Timed span context manager, timed by a :class:`phase` of the same
+    name. No-op (one contextvar read) when the request is unsampled.
+    Within the block the current context points at this span, so nested
+    spans — including remote ones, via the envelope — parent correctly."""
     c = ctx if ctx is not None else _ctx_var.get()
     if c is None or not c.sampled:
         yield None
         return
     sid = _new_id()
     token = _ctx_var.set(c.child(sid))
-    t0w = time.time()
-    t0 = time.perf_counter()
     mutable_attrs = dict(attrs)
+    ph = phase(name)
     try:
-        yield mutable_attrs
+        with ph:
+            yield mutable_attrs
     finally:
         _ctx_var.reset(token)
-        record_span(name, c, t0w, time.perf_counter() - t0,
-                    span_id=sid, **mutable_attrs)
+        record_span(name, c, ph.wall, ph.dur, span_id=sid, **mutable_attrs)
 
 
 def record_event(kind: str, cause: str = "", node: Optional[str] = None,

@@ -618,34 +618,33 @@ class ServeEngine:
                     consumed=c * self._ps, start_page=c)
                 continue
             try:
-                t0w, t0 = time.time(), time.perf_counter()
-                key = self._split_key()
-                if c:
-                    flat = self._gather(self._state, jnp.int32(i),
-                                        self._pages_arr(row_pages))
-                    logits, slot_state = self._extend(
-                        self._params, flat,
-                        jnp.asarray(req.prompt[None, c * self._ps:]),
-                        jnp.int32(c * self._ps))
-                    nxt = self._sampler(logits, key)
-                else:
-                    nxt, slot_state = self._prefill(
-                        self._params, jnp.asarray(req.prompt[None]), key)
-                if self._has_paged:
-                    arr = self._pages_arr(row_pages)
-                    self._state = self._write_paged(
-                        self._state, slot_state, jnp.int32(i), arr,
-                        jnp.int32(c))
-                    self._pages_tab[i] = arr
-                    self._register_prefix(req.prompt, row_pages)
-                else:
-                    self._state = self._write(self._state, slot_state,
-                                              jnp.int32(i))
-                first = int(np.asarray(nxt)[0, 0])
+                with telemetry.phase("engine.prefill") as ph:
+                    key = self._split_key()
+                    if c:
+                        flat = self._gather(self._state, jnp.int32(i),
+                                            self._pages_arr(row_pages))
+                        logits, slot_state = self._extend(
+                            self._params, flat,
+                            jnp.asarray(req.prompt[None, c * self._ps:]),
+                            jnp.int32(c * self._ps))
+                        nxt = self._sampler(logits, key)
+                    else:
+                        nxt, slot_state = self._prefill(
+                            self._params, jnp.asarray(req.prompt[None]), key)
+                    if self._has_paged:
+                        arr = self._pages_arr(row_pages)
+                        self._state = self._write_paged(
+                            self._state, slot_state, jnp.int32(i), arr,
+                            jnp.int32(c))
+                        self._pages_tab[i] = arr
+                        self._register_prefix(req.prompt, row_pages)
+                    else:
+                        self._state = self._write(self._state, slot_state,
+                                                  jnp.int32(i))
+                    first = int(np.asarray(nxt)[0, 0])
                 if req.ctx is not None:
                     telemetry.record_span(
-                        "prefill", req.ctx, t0w,
-                        time.perf_counter() - t0, node=self._node,
+                        "prefill", req.ctx, ph.wall, ph.dur, node=self._node,
                         path="direct",
                         tokens=len(req.prompt) - c * self._ps)
             except Exception as exc:                        # noqa: BLE001
@@ -667,26 +666,37 @@ class ServeEngine:
         decode windows. The final chunk's logits seed the first generated
         token, and only then does the accumulated B=1 state land in the
         reserved slot row. Returns True if a chunk ran."""
-        import jax.numpy as jnp
         p = self._pending
         if p is None:
             return False
+        c0 = p.consumed
+        with telemetry.phase("engine.prefill_chunk") as ph:
+            first = self._extend_pending(p)
+        if p.request.ctx is not None and p.consumed > c0:
+            telemetry.record_span("prefill", p.request.ctx, ph.wall, ph.dur,
+                                  node=self._node, path="chunked",
+                                  tokens=p.consumed - c0)
+        if first is not None:
+            self._pending = None
+            self._activate(p.request, p.slot, first, path="chunked")
+        return True
+
+    def _extend_pending(self, p: _PendingPrefill) -> Optional[int]:
+        """Prefill the next chunk of ``p``; after its last chunk, sample
+        the first token and land the state in the reserved row. Returns
+        the first token, or None while chunks remain or if it failed (the
+        request is then failed and its slot and pages released)."""
+        import jax.numpy as jnp
         prompt = p.request.prompt
         c0 = p.consumed
         c1 = min(c0 + self._chunk, len(prompt))
-        t0w, t0 = time.time(), time.perf_counter()
         try:
             toks = jnp.asarray(prompt[None, c0:c1])
             logits, p.state = self._extend(self._params, p.state, toks,
                                            jnp.int32(c0))
             p.consumed = c1
-            if p.request.ctx is not None:
-                telemetry.record_span("prefill", p.request.ctx, t0w,
-                                      time.perf_counter() - t0,
-                                      node=self._node, path="chunked",
-                                      tokens=c1 - c0)
             if c1 < len(prompt):
-                return True
+                return None
             nxt = self._sampler(logits, self._split_key())
             first = int(np.asarray(nxt)[0, 0])
             if self._has_paged:
@@ -710,10 +720,8 @@ class ServeEngine:
             with self._lock:
                 self._counters["failed"] += 1
             p.request.future.set_exception(exc)
-            return True
-        self._pending = None
-        self._activate(p.request, p.slot, first, path="chunked")
-        return True
+            return None
+        return first
 
     def _split_key(self):
         if self._key is None:
@@ -736,11 +744,12 @@ class ServeEngine:
         length."""
         self._apply_pending_swap()      # between windows, before admission
         progressed = False
-        for _ in range(self._sync):
-            progressed |= self._advance_chunk()
-            self._admit()
-            if self._pending is None:
-                break
+        with telemetry.phase("engine.admit"):
+            for _ in range(self._sync):
+                progressed |= self._advance_chunk()
+                self._admit()
+                if self._pending is None:
+                    break
         active = [i for i, s in enumerate(self._slots) if s is not None]
         if not active:
             return 1 if progressed else 0
@@ -761,50 +770,52 @@ class ServeEngine:
             if score > best:
                 best, k_eff = score, k
             k = min(k * 2, self._sync) if k < self._sync else k * 2
-        t0w = time.time()
-        t0 = time.perf_counter()
-        row_of = None
-        if self._compact:
-            # Window width = active count padded up to a compiled ladder
-            # width: the executable reads [W] page-table rows / feed
-            # tokens / positions, never the slot count, so idle capacity
-            # rows cost zero compute. Pad rows carry an all-trash table
-            # and t=0 (all-invalid attention -> zeros); their writes land
-            # in the trash page. The feed operands rebuild from host slot
-            # state — a few dozen bytes per window.
-            W = self._window_width(len(active))
-            toks_w = np.zeros((W, 1), np.int32)
-            t_w = np.zeros((W,), np.int32)
-            pages_w = np.zeros((W, self._n_log), np.int32)
-            for w, i in enumerate(active):
-                s = self._slots[i]
-                toks_w[w, 0] = s.generated[-1]
-                t_w[w] = s.t
-                pages_w[w] = self._pages_tab[i]
-            toks, self._state, _, _, key = \
-                self._fused(k_eff)(self._params, self._state, toks_w, t_w,
-                                   self._key, pages_w)
-            row_of = {i: w for w, i in enumerate(active)}
-        elif self._has_paged:
-            toks, self._state, self._tokens_dev, self._t_dev, key = \
-                self._fused(k_eff)(self._params, self._state,
-                                   self._tokens_dev, self._t_dev, self._key,
-                                   self._pages_tab)
-        else:
-            toks, self._state, self._tokens_dev, self._t_dev, key = \
-                self._fused(k_eff)(self._params, self._state,
-                                   self._tokens_dev, self._t_dev, self._key)
-        if self._key is not None:
-            self._key = key
-        toks = np.asarray(toks)           # ONE host sync per K-token window
-        win_dur = time.perf_counter() - t0
-        us_tok = win_dur * 1e6 / (len(active) * k_eff)
+        # One phase per window, from building the feed operands through
+        # the token pull: its duration is the window's rate and every
+        # sampled row's decode span.
+        with telemetry.phase(f"engine.window.k{k_eff}") as ph:
+            row_of = None
+            if self._compact:
+                # Window width = active count padded up to a compiled ladder
+                # width: the executable reads [W] page-table rows / feed
+                # tokens / positions, never the slot count, so idle capacity
+                # rows cost zero compute. Pad rows carry an all-trash table
+                # and t=0 (all-invalid attention -> zeros); their writes land
+                # in the trash page. The feed operands rebuild from host slot
+                # state — a few dozen bytes per window.
+                W = self._window_width(len(active))
+                toks_w = np.zeros((W, 1), np.int32)
+                t_w = np.zeros((W,), np.int32)
+                pages_w = np.zeros((W, self._n_log), np.int32)
+                for w, i in enumerate(active):
+                    s = self._slots[i]
+                    toks_w[w, 0] = s.generated[-1]
+                    t_w[w] = s.t
+                    pages_w[w] = self._pages_tab[i]
+                toks, self._state, _, _, key = \
+                    self._fused(k_eff)(self._params, self._state, toks_w,
+                                       t_w, self._key, pages_w)
+                row_of = {i: w for w, i in enumerate(active)}
+            elif self._has_paged:
+                toks, self._state, self._tokens_dev, self._t_dev, key = \
+                    self._fused(k_eff)(self._params, self._state,
+                                       self._tokens_dev, self._t_dev,
+                                       self._key, self._pages_tab)
+            else:
+                toks, self._state, self._tokens_dev, self._t_dev, key = \
+                    self._fused(k_eff)(self._params, self._state,
+                                       self._tokens_dev, self._t_dev,
+                                       self._key)
+            if self._key is not None:
+                self._key = key
+            toks = np.asarray(toks)       # ONE host sync per K-token window
+        us_tok = ph.dur * 1e6 / (len(active) * k_eff)
         # Each sampled in-flight request gets this window as a span — the
         # loop is a no-op (ctx is None) unless a trace is actually live.
         for i in active:
             rq = self._slots[i].request
             if rq.ctx is not None:
-                telemetry.record_span("decode", rq.ctx, t0w, win_dur,
+                telemetry.record_span("decode", rq.ctx, ph.wall, ph.dur,
                                       node=self._node, k=k_eff,
                                       active=len(active))
         with self._lock:
@@ -933,7 +944,11 @@ class ServeEngine:
                 self.ready.set()
                 while not self._stop.is_set():
                     if self.step() == 0:
-                        self._wake.wait(timeout=0.05)
+                        # One phase per wait: a phase open when a profiler
+                        # session starts is not recorded, so short ones
+                        # lose at most one wait.
+                        with telemetry.phase("engine.idle"):
+                            self._wake.wait(timeout=0.05)
                         self._wake.clear()
             except Exception as exc:                        # noqa: BLE001
                 # A warm-up or step that raises (a compile error, a device

@@ -401,6 +401,93 @@ def test_engine_spans_and_ttft():
     assert ttft and any(hists[k]["count"] >= 1 for k in ttft)
 
 
+def _tiny_engine(**kw):
+    import jax
+    from repro import configs
+    from repro.models import transformer
+    from repro.serve.engine import ServeEngine
+
+    cfg = configs.get_reduced("qwen2-1.5b")
+    params = transformer.init_params(cfg, jax.random.key(0))
+    return cfg, ServeEngine(cfg, params, num_slots=2, context_len=24,
+                            max_new=4, **kw)
+
+
+def test_engine_phases_land_in_the_profiler_trace(tmp_path):
+    """A paged engine's loop served under ``jax.profiler``: its phases are
+    host events of the trace, read back by the benchmark's loader."""
+    import re
+    import jax
+    from bench import trace
+
+    cfg, engine = _tiny_engine(page_size=4)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with engine:
+            fut = engine.submit(np.arange(5, dtype=np.int32) % cfg.vocab_size)
+            assert fut.result(timeout=120).shape == (9,)
+            time.sleep(0.15)                    # at least one whole wait
+    finally:
+        jax.profiler.stop_trace()
+    names = {e["name"] for e in trace.load(str(tmp_path))
+             if e["plane"] == trace.HOST_PLANE}
+    assert {"engine.prefill", "engine.idle", "engine.admit"} <= names
+    assert any(re.fullmatch(r"engine\.window\.k\d+", n) for n in names)
+
+
+def test_phase_records_nothing_without_a_session(tmp_path):
+    """A phase outside a profiler session only times itself; inside one it
+    is a host event of the trace, as is a sampled span."""
+    import jax
+    from bench import trace
+
+    with telemetry.phase("outside") as ph:
+        time.sleep(0.002)
+    assert ph.dur >= 0.002 and ph.wall <= time.time()
+    assert telemetry.spans_buffer().drain() == []
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.phase("inside"):
+            pass
+        with telemetry.activate(telemetry.start_trace()):
+            with telemetry.span("sampled"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    names = {e["name"] for e in trace.load(str(tmp_path))}
+    assert {"inside", "sampled"} <= names and "outside" not in names
+
+
+def test_sampled_spans_take_their_times_from_the_phases(monkeypatch):
+    """A sampled request's ``decode`` spans are its windows' phases and its
+    ``prefill`` span the prefill phase: one timing, not two."""
+    timed = []
+
+    class Recorded(telemetry.phase):
+        __slots__ = ()
+
+        def __exit__(self, *exc_info):
+            super().__exit__(*exc_info)
+            timed.append(self)
+
+    monkeypatch.setattr(telemetry, "phase", Recorded)
+    cfg, engine = _tiny_engine()
+    ctx = telemetry.start_trace()
+    with telemetry.activate(ctx):
+        fut = engine.submit(np.arange(5, dtype=np.int32) % cfg.vocab_size)
+    while not fut.done():
+        engine.step()
+    spans = [s for s in telemetry.spans_buffer().drain()
+             if s["trace"] == ctx.trace_id]
+    windows = [(p.wall, p.dur) for p in timed
+               if p.name.startswith("engine.window.k")]
+    decode = [(s["ts"], s["dur"]) for s in spans if s["name"] == "decode"]
+    assert decode and decode == windows
+    (prefill,) = [p for p in timed if p.name == "engine.prefill"]
+    (span,) = [s for s in spans if s["name"] == "prefill"]
+    assert (span["ts"], span["dur"]) == (prefill.wall, prefill.dur)
+
+
 # ---- collector ---------------------------------------------------------------
 
 class FakeNode:
@@ -534,7 +621,7 @@ def test_node_logger_exception_appends_traceback(capsys):
 
 def test_unsampled_hot_path_is_cheap():
     """No trace context active: inject is a dict passthrough and span a
-    no-op — the invariant the <= 1.03x bench gate rests on."""
+    no-op, so unsampled requests pay next to nothing."""
     kwargs = {"max_new": 4}
     assert telemetry.inject(kwargs) is kwargs
     h = telemetry.metrics().histogram("bench.sanity")
