@@ -135,10 +135,12 @@ class EngineServer:
     :class:`~repro.ckpt.checkpoint.ModelStore` (``version=None`` means
     latest) instead of fresh init, and ``load_version()`` hot-swaps to
     another published version: the restore is checked against the
-    current tree (shape identity — same architecture or the RPC fails,
+    masters' layout (shape identity — same architecture or the RPC fails,
     which is the rollout's health gate firing) and installed between
     decode windows, so the compiled ladder stays warm and in-flight
-    requests keep decoding.
+    requests keep decoding. The engine serves a copy of the weights in
+    the compute dtype (``transformer.serving_params``), made once per
+    load and per version swap; the float32 masters are not kept.
     """
 
     def __init__(self, model_cfg: ModelConfig, max_new: int = 8,
@@ -172,8 +174,9 @@ class EngineServer:
             device = mesh.devices.flat[0]
         on_device = (contextlib.nullcontext() if device is None
                      else jax.default_device(device))
-        with on_device:
-            params = transformer.init_params(model_cfg, jax.random.key(0))
+        # The masters' layout: what a published version restores against.
+        self._masters = jax.eval_shape(
+            lambda: transformer.init_params(model_cfg, jax.random.key(0)))
         if store_dir is not None:
             from repro.ckpt.checkpoint import ModelStore
             self._store = ModelStore(store_dir)
@@ -184,8 +187,16 @@ class EngineServer:
             # The store returns host arrays; the engine lives where its
             # params are.
             params = jax.device_put(
-                self._store.load_version(int(v), like=params), device)
+                self._store.load_version(int(v), like=self._masters), device)
             self._version = int(v)
+        else:
+            with on_device:
+                params = transformer.init_params(model_cfg,
+                                                 jax.random.key(0))
+        # Cast here, so the float32 masters are freed before the engine
+        # allocates its KV state (the engine's own cast then passes the
+        # serving copy through).
+        params = transformer.serving_params(model_cfg, params)
         self._engine = ServeEngine(
             model_cfg, params, num_slots=num_slots,
             context_len=context_len or 128,
@@ -252,14 +263,13 @@ class EngineServer:
 
     def load_version(self, version):
         """Hot-swap to a published model version (the rollout's swap
-        step). Restores against the current tree — a version published
+        step). Restores against the masters' layout — a version published
         for a different architecture fails *here*, before any weight is
         installed — then applies between decode windows."""
         if self._store is None:
             raise RuntimeError("EngineServer has no model store attached "
                                "(pass store_dir=)")
-        params = self._store.load_version(int(version),
-                                          like=self._engine._params)
+        params = self._store.load_version(int(version), like=self._masters)
         self._engine.swap_params(params)
         self._version = int(version)
         if self._heartbeater is not None:

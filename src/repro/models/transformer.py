@@ -97,6 +97,48 @@ def param_shapes(cfg: ModelConfig) -> Any:
         lambda k: init_params(cfg, k), jax.random.key(0))
 
 
+# MoE expert stacks: bare [E, ...] arrays that moe.apply_moe casts before
+# its einsums (a dense MLP keeps linear dicts under the same names).
+_EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+
+def serving_params(cfg: ModelConfig, params: dict) -> dict:
+    """The weights a server decodes from: ``params`` with every leaf that
+    all its readers cast to the compute dtype before use already in it.
+
+    Those are a linear layer's ``kernel`` and ``bias``
+    (``layers.apply_linear``: attention, MLP, MoE router, recurrent and
+    SSM projections, the untied head), the embedding table
+    (``embed_tokens`` and the tied ``lm_logits``) and MoE expert stacks.
+    Every other leaf keeps its dtype: norm scales and biases (read in
+    float32), q/k norms, and the RG-LRU and SSM leaves outside linear
+    layers (gates, Λ, A, D, conv weights), which are small or enter
+    float32 arithmetic. A step therefore computes with the same rounded
+    values as from the masters, with the cast paid once here instead of
+    in every compiled call. A leaf already in the compute dtype is
+    returned as the same array, so bfloat16 masters cost no copy.
+    """
+    dt = layers.cdtype(cfg)
+
+    def cast(x):
+        return x if x.dtype == dt else x.astype(dt)
+
+    def walk(node: dict, name: str) -> dict:
+        out = {}
+        for k, v in node.items():
+            if isinstance(v, dict):
+                out[k] = walk(v, k)
+            elif ((k in ("kernel", "bias") and "kernel" in node)
+                  or (name == "embed" and k == "tokens")
+                  or k in _EXPERT_STACKS):
+                out[k] = cast(v)
+            else:
+                out[k] = v
+        return out
+
+    return walk(params, "")
+
+
 # ---------------------------------------------------------------------------
 # Full-sequence block application (train / prefill)
 # ---------------------------------------------------------------------------

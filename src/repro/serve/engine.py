@@ -43,7 +43,11 @@ replaces that with *iteration-level* scheduling:
   * a sequence retires the moment it finishes (EOS or its ``max_new``
     budget) and its slot is immediately reusable — nobody waits for a
     batch-mate;
-  * replies stream back per request through ``concurrent.futures``.
+  * replies stream back per request through ``concurrent.futures``;
+  * the engine decodes from ``transformer.serving_params`` of the
+    weights it is given, made once per load and per ``swap_params``:
+    no compiled call receives a float32 weight that it would cast to
+    the compute dtype again on every call.
 
 Exact-length prefill (no padding) keeps admission correct for every
 ``decode_supported`` architecture, including the recurrent ones
@@ -193,8 +197,11 @@ class ServeEngine:
         leaf = jax.tree.leaves(params)[0]
         devices = leaf.devices() if isinstance(leaf, jax.Array) else set()
         self._device = next(iter(devices)) if len(devices) == 1 else None
+        # Executables key on whether their arguments are committed to a
+        # device, so swapped-in weights keep the live weights' commitment.
+        self._committed = isinstance(leaf, jax.Array) and leaf.committed
         self._cfg = cfg
-        self._params = params
+        self._params = transformer.serving_params(cfg, params)
         self._ns = num_slots
         self._L = context_len
         self._max_new = max_new
@@ -346,7 +353,7 @@ class ServeEngine:
                               steps=0, decode_tokens=0, generated_tokens=0,
                               occupancy_sum=0, peak_occupancy=0,
                               host_syncs=0, prefix_tokens_reused=0,
-                              param_swaps=0)
+                              param_swaps=0, param_casts=1)
         # Weight hot-swap handoff: (params, applied-event), installed by
         # the engine thread at the top of its next step.
         self._pending_swap: Optional[tuple[Any, threading.Event]] = None
@@ -427,16 +434,25 @@ class ServeEngine:
         operand to every compiled executable, a shape/dtype-identical
         swap reuses the entire warmed ladder: no recompile, no re-warm
         cost (``EngineServer.load_version`` enforces shape identity by
-        restoring against the current tree).
+        restoring against the masters' layout).
+
+        The tree is moved to the engine's device and cast to its serving
+        copy (``transformer.serving_params``) here, on the caller's
+        thread, so no window waits on the cast.
 
         With ``block=True`` (and a running engine thread) waits until the
         swap has been applied. When the engine is driven by external
         ``step()`` calls, the swap lands on the caller's next step.
         """
+        import jax
+        from repro.models import transformer
+        params = jax.block_until_ready(
+            transformer.serving_params(self._cfg, self._place(params)))
         done = threading.Event()
         with self._lock:
             if self._closed:
                 raise RuntimeError("engine stopped")
+            self._counters["param_casts"] += 1
             prev = self._pending_swap
             self._pending_swap = (params, done)
         if prev is not None:
@@ -447,16 +463,27 @@ class ServeEngine:
                 raise TimeoutError("param swap not applied within "
                                    f"{timeout_s}s")
 
+    def _place(self, params):
+        """``params`` on this engine's device, committed there only if
+        the live weights are, so the warmed executables still match."""
+        import jax
+        if self._device is None:
+            return params
+
+        def put(x):
+            if self._committed or (isinstance(x, jax.Array)
+                                   and x.devices() != {self._device}):
+                return jax.device_put(x, self._device)
+            with self._on_device():
+                return jax.device_put(x)
+        return jax.tree.map(put, params)
+
     def _apply_pending_swap(self) -> None:
         with self._lock:
             swap, self._pending_swap = self._pending_swap, None
         if swap is None:
             return
-        params, done = swap
-        if self._device is not None:
-            import jax
-            params = jax.device_put(params, self._device)
-        self._params = params
+        self._params, done = swap
         with self._lock:
             self._counters["param_swaps"] += 1
         done.set()
