@@ -17,6 +17,7 @@ _ARCHS = {
     "mixtral-8x7b": "mixtral_8x7b",
     "recurrentgemma-2b": "recurrentgemma_2b",
     "falcon-mamba-7b": "falcon_mamba_7b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ARCH_NAMES = tuple(_ARCHS)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.kernels import decode_attention as _dec
 from repro.kernels import flash_attention as _fa
+from repro.kernels import mla_decode as _mla
 from repro.kernels import ref as _ref
 from repro.kernels import rglru_scan as _rg
 from repro.kernels import ssm_scan as _ssm
@@ -165,6 +166,44 @@ def paged_decode_attention(q, k_pages, v_pages, pages, valid,
         return _paged_decode_ref(q, k_pages, v_pages, pages, valid)
     interpret = _auto_interpret() if interpret is None else interpret
     return _paged_decode_pallas(q, k_pages, v_pages, pages, valid, interpret)
+
+
+MLA_PAGES_PER_BLOCK = 16     # pages the MLA kernel gathers per block
+
+
+@functools.partial(jax.jit, static_argnames=("value", "sm_scale",
+                                             "pages_per_block", "interpret"))
+def _mla_decode_pallas(q, pool, layer, pages, lengths, value: int,
+                       sm_scale: float, pages_per_block: int,
+                       interpret: bool):
+    return _mla.mla_decode(q, pool, layer, pages, lengths, value=value,
+                           sm_scale=sm_scale,
+                           pages_per_block=pages_per_block,
+                           interpret=interpret)
+
+
+_mla_decode_ref = jax.jit(_ref.mla_decode,
+                          static_argnames=("value", "sm_scale"))
+
+
+def mla_decode(q, pool, layer, pages, lengths, *, value: int,
+               sm_scale: float, interpret: Optional[bool] = None,
+               impl: Optional[str] = None):
+    """Backend-dispatched paged latent-attention decode (the rule of
+    ``resolve_decode_impl``). q [B,H,W]; pool [Lyr,P,ps,W] read in place;
+    layer int32 scalar; pages [B,n]; lengths [B] -> [B,H,value]. The
+    kernel path pads the page table to whole blocks with the trash page,
+    a few hundred bytes per call."""
+    if resolve_decode_impl(impl, interpret) == "ref":
+        return _mla_decode_ref(q, pool, layer, pages, lengths, value=value,
+                               sm_scale=sm_scale)
+    interpret = _auto_interpret() if interpret is None else interpret
+    ppb = min(MLA_PAGES_PER_BLOCK, pages.shape[1])
+    pad = -pages.shape[1] % ppb
+    if pad:
+        pages = jnp.pad(pages, ((0, 0), (0, pad)))
+    return _mla_decode_pallas(q, pool, layer, pages, lengths, value,
+                              sm_scale, ppb, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "block_w",

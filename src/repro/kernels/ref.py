@@ -90,6 +90,28 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     return decode_attention(q, k, v, valid, sm_scale)
 
 
+def mla_decode(q: jax.Array, pool: jax.Array, layer: jax.Array,
+               pages: jax.Array, lengths: jax.Array, *, value: int,
+               sm_scale: float) -> jax.Array:
+    """Paged latent-attention decode (``kernels.mla_decode``'s contract).
+
+    q [B,H,W]; pool [Lyr,P,ps,W]; pages [B,n]; lengths [B] -> [B,H,value]:
+    gather layer ``layer``'s pages of each row into its logical [n*ps, W]
+    view, score every head against every entry at a position below the
+    row's length, and sum the entries' first ``value`` columns under the
+    softmax.
+    """
+    B = q.shape[0]
+    ps, W = pool.shape[2:]
+    kv = pool[layer][pages].reshape(B, -1, W).astype(jnp.float32)
+    logits = jnp.einsum("bhw,blw->bhl", q.astype(jnp.float32), kv) * sm_scale
+    pos = jnp.arange(kv.shape[1], dtype=jnp.int32)
+    logits = jnp.where(pos[None, None, :] < lengths[:, None, None], logits,
+                       NEG_INF)
+    w = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bhl,blv->bhv", w, kv[..., :value]).astype(q.dtype)
+
+
 def rglru_scan(a: jax.Array, x: jax.Array, h0: jax.Array) -> tuple:
     """Sequential linear recurrence h_t = a_t h_{t-1} + x_t (all fp32).
 

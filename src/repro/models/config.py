@@ -21,6 +21,10 @@ LOCAL = "local"        # local attention, RecurrentGemma style (+ MLP)
 XATTN = "xattn"        # cross-attention to frontend embeddings (+ MLP)
 RGLRU = "rglru"        # RG-LRU recurrent block (+ MLP)
 MAMBA = "mamba"        # Mamba-1 block (no separate MLP)
+MLA = "mla"            # multi-head latent attention, DeepSeek-V2 (+ MLP)
+
+# Kinds whose decode cache sits behind the page table in paged mode.
+PAGED_KINDS = frozenset({ATTN, MLA})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,11 +66,38 @@ class ModelConfig:
     conv_pos_width: int = 128
     conv_pos_groups: int = 16
 
+    # --- MLA (DeepSeek-V2; ``pattern`` kind "mla") ------------------------------
+    kv_lora_rank: int = 0                    # latent width cached per token
+    qk_nope_dim: int = 0                     # per-head q/k width without RoPE
+    qk_rope_dim: int = 0                     # per-head (q) / shared (k) RoPE width
+    v_head_dim: int = 0
+
+    # --- YaRN RoPE (scalars: a config stays hashable) ------------------------
+    rope_scaling_factor: float = 1.0         # 1 = no YaRN
+    rope_original_len: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+
     # --- MoE -----------------------------------------------------------------
-    num_experts: int = 0
+    num_experts: int = 0                     # router width
     experts_per_token: int = 0
     moe_capacity_factor: float = 1.25
     router_aux_loss: float = 0.02
+    # Leading layers of kind ``pattern[0]`` whose MLP is the dense ``d_ff``
+    # one (DeepSeek's first_k_dense_replace); the pattern repeats after them.
+    first_dense: int = 0
+    # The serving expert layer (``moe.apply_expert_share``), chosen by
+    # ``moe_d_ff``: experts of that width, of which this replica holds
+    # ``experts_held`` starting at ``expert_offset`` (0 = all), softmax
+    # routing over all ``num_experts``, top-k gates as DeepSeek-V2 takes them
+    # (not renormalised, scale 1), plus one always-on shared SwiGLU of width
+    # ``shared_d_ff``.
+    moe_d_ff: int = 0
+    experts_held: int = 0
+    expert_offset: int = 0
+    shared_d_ff: int = 0
 
     # --- SSM (Mamba-1) ----------------------------------------------------------
     ssm_state: int = 0
@@ -94,22 +125,53 @@ class ModelConfig:
         if self.ssm_state and not self.ssm_dt_rank:
             object.__setattr__(self, "ssm_dt_rank",
                                int(math.ceil(self.d_model / 16)))
-        if self.num_layers % len(self.pattern) and self.family == "moe":
-            raise ValueError("MoE stacks must tile the pattern exactly")
+        if self.num_experts and not self.experts_held:
+            object.__setattr__(self, "experts_held", self.num_experts)
+        if (self.num_layers - self.first_dense) % len(self.pattern) \
+                and self.family == "moe":
+            raise ValueError("MoE stacks must tile the pattern exactly "
+                             "after their leading dense layers")
 
     # --- pattern helpers -----------------------------------------------------
     @property
     def num_repeats(self) -> int:
-        return self.num_layers // len(self.pattern)
+        return (self.num_layers - self.first_dense) // len(self.pattern)
 
     @property
     def remainder(self) -> tuple[str, ...]:
-        return self.pattern[: self.num_layers % len(self.pattern)]
+        n = (self.num_layers - self.first_dense) % len(self.pattern)
+        return self.pattern[:n]
+
+    @property
+    def lead(self) -> tuple[str, ...]:
+        """Kinds of the leading dense-MLP layers (unrolled, before the
+        scanned repeats)."""
+        return (self.pattern[0],) * self.first_dense
+
+    @property
+    def kinds(self) -> frozenset:
+        return frozenset(self.pattern) | frozenset(self.remainder)
+
+    @property
+    def expert_share(self) -> bool:
+        """MoE layers are the serving expert-share layer, not Mixtral's."""
+        return bool(self.num_experts and self.moe_d_ff)
+
+    @property
+    def mla_softmax_scale(self) -> float:
+        """(qk_nope + qk_rope)^-0.5, times DeepSeek's YaRN mscale squared
+        when YaRN is on."""
+        s = (self.qk_nope_dim + self.qk_rope_dim) ** -0.5
+        if self.rope_scaling_factor > 1 and self.rope_mscale_all_dim:
+            m = 0.1 * self.rope_mscale_all_dim \
+                * math.log(self.rope_scaling_factor) + 1.0
+            s *= m * m
+        return s
 
     @property
     def attention_free(self) -> bool:
         kinds = set(self.pattern) | set(self.remainder)
-        return not (kinds & {ATTN, SWA, LOCAL, XATTN})
+        return not (kinds & {ATTN, SWA, LOCAL, XATTN, MLA})
 
     @property
     def decode_supported(self) -> bool:
@@ -119,7 +181,7 @@ class ModelConfig:
     def subquadratic(self) -> bool:
         """True if per-token serve cost is O(1) in context length."""
         kinds = set(self.pattern) | set(self.remainder)
-        return ATTN not in kinds and XATTN not in kinds
+        return not kinds & {ATTN, XATTN, MLA}
 
     # --- parameter counting (for MODEL_FLOPS = 6·N·D) -------------------------
     def _block_params(self, kind: str) -> int:

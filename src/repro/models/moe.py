@@ -1,14 +1,22 @@
-"""Mixtral-style token-choice top-k MoE with dense (GShard) dispatch.
+"""Mixture-of-experts layers.
 
-TPU-native formulation: top-k routing builds dispatch/combine tensors and
-experts run as stacked einsums — no gather/scatter, fully shardable.
-Expert weights are 2-D sharded ``P(None, 'data', 'model')`` (FSDP × TP);
-the dispatch einsums induce the all-to-all-equivalent collectives under
-SPMD. An auxiliary load-balancing loss (Switch style) is returned to the
-trainer.
+``apply_moe`` is Mixtral's training layer: token-choice top-k with dense
+(GShard) dispatch. Top-k routing builds dispatch/combine tensors and experts
+run as stacked einsums — no gather/scatter, fully shardable. Expert weights
+are 2-D sharded ``P(None, 'data', 'model')`` (FSDP × TP); the dispatch
+einsums induce the all-to-all-equivalent collectives under SPMD. An
+auxiliary load-balancing loss (Switch style) is returned to the trainer. Its
+capacity factor drops tokens and couples the rows of a batch.
+
+``apply_expert_share`` is the serving layer (DeepSeek-V2's fine-grained
+experts plus shared experts), for a replica that holds only its share of
+the experts: it routes over all ``num_experts``, computes only the
+``experts_held`` it holds, drops nothing and treats every token alone.
 """
 
 from __future__ import annotations
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -89,3 +97,55 @@ def apply_moe(cfg: ModelConfig, p: dict, x: jax.Array
     p_e = probs.mean(axis=(0, 1))                              # mean router prob
     aux = E * jnp.sum(f_e * p_e) * cfg.router_aux_loss
     return y.reshape(B, S, D), aux
+
+
+# ---------------------------------------------------------------------------
+# Serving: this replica's share of fine-grained experts + a shared expert
+# ---------------------------------------------------------------------------
+
+def init_expert_share(cfg: ModelConfig, key) -> dict:
+    d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.experts_held
+    ks = jax.random.split(key, 5)
+    s_in, s_out = d ** -0.5, f ** -0.5
+    shared = dataclasses.replace(cfg, d_ff=cfg.shared_d_ff, mlp="swiglu",
+                                 mlp_bias=False)
+    return {
+        "router": layers.init_linear(ks[0], d, cfg.num_experts),
+        "w_gate": jax.random.normal(ks[1], (e, d, f), jnp.float32) * s_in,
+        "w_up": jax.random.normal(ks[2], (e, d, f), jnp.float32) * s_in,
+        "w_down": jax.random.normal(ks[3], (e, f, d), jnp.float32) * s_out,
+        "shared": layers.init_mlp(shared, ks[4]),
+    }
+
+
+def expert_gates(cfg: ModelConfig, router: dict, x: jax.Array) -> jax.Array:
+    """Gates of the held experts [..., experts_held], float32: softmax of
+    the router over all ``num_experts``, zero outside each token's top-k
+    (DeepSeek-V2's routing: not renormalised, scale 1), then the held
+    slice."""
+    # Products of compute-dtype values, summed in float32: the published
+    # router's float32 logits of bfloat16 activations and weights.
+    logits = jnp.dot(x, router["kernel"].astype(x.dtype),
+                     preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates = probs * _topk_mask(probs, cfg.experts_per_token)
+    return gates[..., cfg.expert_offset:cfg.expert_offset + cfg.experts_held]
+
+
+def apply_expert_share(cfg: ModelConfig, p: dict, x: jax.Array
+                       ) -> tuple[jax.Array, jax.Array]:
+    """x [B, S, D] -> (out [B, S, D], routed pairs on held experts [B, S]).
+
+    out = Σ over held experts of gate · SwiGLU_e(x) + SwiGLU_shared(x). Every
+    held expert runs on every token (a zero gate contributes zero): at
+    decode batch sizes an expert matmul reads its weights once whatever the
+    rows, and no token is ever dropped or depends on its neighbours."""
+    gates = expert_gates(cfg, p["router"], x)               # [B,S,e]
+    dt = x.dtype
+    h = jax.nn.silu(jnp.einsum("bsd,edf->bsef", x, p["w_gate"].astype(dt)))
+    h = h * jnp.einsum("bsd,edf->bsef", x, p["w_up"].astype(dt))
+    h = h * gates.astype(dt)[..., None]
+    y = jnp.einsum("bsef,efd->bsd", h, p["w_down"].astype(dt))
+    shared = dataclasses.replace(cfg, mlp="swiglu", mlp_bias=False)
+    y = y + layers.apply_mlp(shared, p["shared"], x)
+    return y, (gates > 0).sum(-1).astype(jnp.int32)

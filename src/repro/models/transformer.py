@@ -1,7 +1,9 @@
 """Backbone assembly: superblock pattern -> scan over repeats -> LM heads.
 
-The layer stack is ``cfg.pattern`` repeated ``cfg.num_repeats`` times (with
-stacked params under ``jax.lax.scan``) plus an unrolled remainder. The same
+The layer stack is ``cfg.first_dense`` unrolled leading layers (``lead``:
+kind ``pattern[0]`` with the dense MLP, DeepSeek's first_k_dense_replace),
+then ``cfg.pattern`` repeated ``cfg.num_repeats`` times (with stacked params
+under ``jax.lax.scan``), then an unrolled remainder (``tail``). The same
 block functions serve training/prefill (full sequence) and decode (single
 token + recurrent/KV state).
 """
@@ -14,9 +16,9 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models import attention, layers, moe, rglru, ssm
-from repro.models.config import (ATTN, LOCAL, MAMBA, RGLRU, SWA, XATTN,
-                                 ModelConfig)
+from repro.models import attention, layers, mla, moe, rglru, ssm
+from repro.models.config import (ATTN, LOCAL, MAMBA, MLA, PAGED_KINDS, RGLRU,
+                                 SWA, XATTN, ModelConfig)
 from repro.sharding import shard
 
 # When num_repeats <= this threshold the repeat loop is unrolled in Python
@@ -49,10 +51,46 @@ def _repeat_blocks(body, carry, stacked_params, extra=None):
 # Init
 # ---------------------------------------------------------------------------
 
-def _init_block(cfg: ModelConfig, kind: str, key) -> dict:
+def _groups(cfg: ModelConfig):
+    """(group, kinds, axis of a state leaf's rows) of each layer group in
+    stack order; ``blocks`` leaves carry the repeat axis first."""
+    return (("lead", cfg.lead, 0), ("blocks", cfg.pattern, 1),
+            ("tail", cfg.remainder, 0))
+
+
+def paged_in_place(cfg: ModelConfig) -> bool:
+    """Whether paged decode reads and writes the page pool in place (every
+    layer latent attention), rather than through a flat per-row view."""
+    return cfg.kinds == {MLA}
+
+
+def _init_mlp(cfg: ModelConfig, key, dense: bool) -> dict:
+    if dense or not cfg.num_experts:
+        return layers.init_mlp(cfg, key)
+    if cfg.expert_share:
+        return moe.init_expert_share(cfg, key)
+    return moe.init_moe(cfg, key)
+
+
+def _mlp(cfg: ModelConfig, p: dict, h: jax.Array, dense: bool = False):
+    """(out, aux loss, routed pairs on held experts per token or None)."""
+    if dense or not cfg.num_experts:
+        return layers.apply_mlp(cfg, p, h), None, None
+    if cfg.expert_share:
+        y, pairs = moe.apply_expert_share(cfg, p, h)
+        return y, None, pairs
+    y, aux = moe.apply_moe(cfg, p, h)
+    return y, aux, None
+
+
+def _init_block(cfg: ModelConfig, kind: str, key, dense: bool = False) -> dict:
     ks = jax.random.split(key, 4)
     p: dict[str, Any] = {"norm": layers.init_norm(cfg)}
-    if kind in (ATTN, SWA, LOCAL, XATTN):
+    if kind == MLA:
+        p["attn"] = mla.init_mla(cfg, ks[0])
+        p["mlp_norm"] = layers.init_norm(cfg)
+        p["mlp"] = _init_mlp(cfg, ks[1], dense)
+    elif kind in (ATTN, SWA, LOCAL, XATTN):
         p["attn"] = attention.init_attention(cfg, ks[0], cross=(kind == XATTN))
         p["mlp_norm"] = layers.init_norm(cfg)
         if cfg.num_experts:
@@ -79,6 +117,12 @@ def _init_superblock(cfg: ModelConfig, key) -> dict:
 def init_params(cfg: ModelConfig, key) -> dict:
     k_embed, k_blocks, k_tail = jax.random.split(key, 3)
     params: dict[str, Any] = {"embed": layers.init_embed(cfg, k_embed)}
+    if cfg.lead:
+        lead_keys = jax.random.split(jax.random.fold_in(k_tail, 1),
+                                     len(cfg.lead))
+        params["lead"] = {str(i): _init_block(cfg, kind, lead_keys[i],
+                                              dense=True)
+                          for i, kind in enumerate(cfg.lead)}
     if cfg.num_repeats:
         rep_keys = jax.random.split(k_blocks, cfg.num_repeats)
         params["blocks"] = jax.vmap(
@@ -144,10 +188,17 @@ def serving_params(cfg: ModelConfig, params: dict) -> dict:
 # ---------------------------------------------------------------------------
 
 def _apply_block(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
-                 positions: jax.Array, memory: Optional[jax.Array]):
+                 positions: jax.Array, memory: Optional[jax.Array],
+                 dense: bool = False):
     """Returns (x, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     h = layers.apply_norm(cfg, p["norm"], x)
+    if kind == MLA:
+        h = mla.self_attention(cfg, p["attn"], h, positions)
+        x = x + h
+        h, a, _ = _mlp(cfg, p["mlp"], layers.apply_norm(cfg, p["mlp_norm"], x),
+                       dense)
+        return x + h, aux if a is None else a
     if kind in (ATTN, SWA, LOCAL):
         h = attention.self_attention(cfg, p["attn"], h, positions, kind)
     elif kind == XATTN:
@@ -203,6 +254,9 @@ def forward(cfg: ModelConfig, params: dict, *,
         memory = memory.astype(x.dtype)
 
     aux_total = jnp.zeros((), jnp.float32)
+    for i, kind in enumerate(cfg.lead):
+        x, _ = _apply_block(cfg, kind, params["lead"][str(i)], x, positions,
+                            memory, dense=True)
 
     def sb_fn(blk_params, h, positions, memory):
         h, a = _apply_superblock(cfg, blk_params, h, positions, memory)
@@ -277,9 +331,16 @@ def loss_fn(cfg: ModelConfig, params: dict, batch: dict,
 
 def _prefill_block(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
                    positions: jax.Array, memory: Optional[jax.Array],
-                   context_len: int, cache_dtype):
+                   context_len: int, cache_dtype, dense: bool = False):
     """Like _apply_block but also returns the block's decode state."""
     h = layers.apply_norm(cfg, p["norm"], x)
+    if kind == MLA:
+        h, entries = mla.self_attention(cfg, p["attn"], h, positions,
+                                        return_cache=True)
+        x = x + h
+        h, _, _ = _mlp(cfg, p["mlp"], layers.apply_norm(cfg, p["mlp_norm"], x),
+                       dense)
+        return x + h, mla.build_cache(entries, context_len, cache_dtype)
     if kind in (ATTN, SWA, LOCAL):
         h, (k, v) = attention.self_attention(cfg, p["attn"], h, positions,
                                              kind, return_kv=True)
@@ -329,6 +390,12 @@ def prefill(cfg: ModelConfig, params: dict, *, tokens=None, memory=None,
         memory = memory.astype(x.dtype)
 
     state: dict[str, Any] = {}
+    if cfg.lead:
+        state["lead"] = {}
+        for i, kind in enumerate(cfg.lead):
+            x, state["lead"][str(i)] = _prefill_block(
+                cfg, kind, params["lead"][str(i)], x, positions, memory,
+                context_len, cache_dtype, dense=True)
     if "blocks" in params:
         def body(h, blk_params):
             blk_state = {}
@@ -360,6 +427,10 @@ def _block_state_spec(cfg: ModelConfig, kind: str, batch: int,
                       context_len: int, dtype,
                       page_size: Optional[int] = None,
                       num_pages: Optional[int] = None) -> dict:
+    if kind == MLA:
+        if page_size is not None:
+            return mla.cache_spec(cfg, num_pages, page_size, dtype)
+        return mla.cache_spec(cfg, batch, context_len, dtype)
     if kind == ATTN and page_size is not None:
         return attention.paged_kv_cache_spec(cfg, num_pages, page_size,
                                              dtype)
@@ -395,6 +466,11 @@ def decode_state_spec(cfg: ModelConfig, batch: int, context_len: int,
                                            s.dtype), one)
 
     state: dict[str, Any] = {}
+    if cfg.lead:
+        state["lead"] = {
+            str(i): _block_state_spec(cfg, kind, batch, context_len, dtype,
+                                      page_size, num_pages)
+            for i, kind in enumerate(cfg.lead)}
     if cfg.num_repeats:
         state["blocks"] = {
             str(i): stack(functools.partial(
@@ -436,6 +512,9 @@ def write_decode_slot(cfg: ModelConfig, state: dict, slot_state: dict,
         return f
 
     out: dict[str, Any] = {}
+    if "lead" in state:
+        out["lead"] = jax.tree.map(_write(0), state["lead"],
+                                   slot_state["lead"])
     if "blocks" in state:
         out["blocks"] = jax.tree.map(_write(1), state["blocks"],
                                      slot_state["blocks"])
@@ -475,6 +554,23 @@ def _paged_leaf_write(dst: jax.Array, src: jax.Array, row_pages: jax.Array,
     return dst.at[:, row_pages].set(upd)            # stacked repeat leads
 
 
+def _stacked_pool_write(dst: jax.Array, src: jax.Array, row_pages: jax.Array,
+                        start_page: jax.Array, page_size: int) -> jax.Array:
+    """``_paged_leaf_write`` for a pool with a leading layer axis, one
+    layer at a time: XLA lays out a gather or scatter across the page axis
+    of the whole stack by copying the stack (a latent pool is most of the
+    device), and one layer's pages cost no such copy."""
+    n_log = row_pages.shape[0]
+    sp = src.reshape((src.shape[0], n_log, page_size) + src.shape[3:])
+    keep = (jnp.arange(n_log, dtype=jnp.int32) >= start_page).reshape(
+        (n_log,) + (1,) * (sp.ndim - 2))
+
+    def one(d, i):
+        upd = jnp.where(keep, sp[i].astype(d.dtype), d[i][row_pages])
+        return d.at[i, row_pages].set(upd), None
+    return jax.lax.scan(one, dst, jnp.arange(dst.shape[0]))[0]
+
+
 def write_paged_slot(cfg: ModelConfig, state: dict, slot_state: dict,
                      index, row_pages: jax.Array, start_page,
                      page_size: int) -> dict:
@@ -494,7 +590,11 @@ def write_paged_slot(cfg: ModelConfig, state: dict, slot_state: dict,
     start_page = jnp.asarray(start_page, jnp.int32)
 
     def _write_kind(kind: str, dst, src, axis: int):
-        if kind == ATTN:
+        if kind == MLA and axis == 1:
+            return jax.tree.map(
+                lambda d, s: _stacked_pool_write(d, s, row_pages, start_page,
+                                                 page_size), dst, src)
+        if kind in PAGED_KINDS:
             return jax.tree.map(
                 lambda d, s: _paged_leaf_write(d, s, row_pages, start_page,
                                                page_size, axis), dst, src)
@@ -502,18 +602,10 @@ def write_paged_slot(cfg: ModelConfig, state: dict, slot_state: dict,
             lambda d, s: jax.lax.dynamic_update_slice_in_dim(
                 d, s.astype(d.dtype), index, axis=axis), dst, src)
 
-    out: dict[str, Any] = {}
-    if "blocks" in state:
-        out["blocks"] = {
-            str(i): _write_kind(kind, state["blocks"][str(i)],
-                                slot_state["blocks"][str(i)], 1)
-            for i, kind in enumerate(cfg.pattern)}
-    if "tail" in state:
-        out["tail"] = {
-            str(i): _write_kind(kind, state["tail"][str(i)],
-                                slot_state["tail"][str(i)], 0)
-            for i, kind in enumerate(cfg.remainder)}
-    return out
+    return {g: {str(i): _write_kind(kind, state[g][str(i)],
+                                    slot_state[g][str(i)], axis)
+                for i, kind in enumerate(kinds)}
+            for g, kinds, axis in _groups(cfg) if g in state}
 
 
 def gather_paged_slot(cfg: ModelConfig, state: dict, index,
@@ -528,9 +620,12 @@ def gather_paged_slot(cfg: ModelConfig, state: dict, index,
     n_log = row_pages.shape[0]
 
     def _gather_kind(kind: str, leaf, axis: int):
-        if kind == ATTN:
+        if kind in PAGED_KINDS:
             def g(pool):
-                out = jnp.take(pool, row_pages, axis=axis)
+                if kind == MLA and axis == 1:   # a layer at a time, as above
+                    out = jax.lax.map(lambda p: p[row_pages], pool)
+                else:
+                    out = jnp.take(pool, row_pages, axis=axis)
                 shape = (pool.shape[:axis] + (1, n_log * page_size)
                          + pool.shape[axis + 2:])
                 return out.reshape(shape)
@@ -539,16 +634,9 @@ def gather_paged_slot(cfg: ModelConfig, state: dict, index,
             lambda a: jax.lax.dynamic_slice_in_dim(a, index, 1, axis=axis),
             leaf)
 
-    out: dict[str, Any] = {}
-    if "blocks" in state:
-        out["blocks"] = {
-            str(i): _gather_kind(kind, state["blocks"][str(i)], 1)
-            for i, kind in enumerate(cfg.pattern)}
-    if "tail" in state:
-        out["tail"] = {
-            str(i): _gather_kind(kind, state["tail"][str(i)], 0)
-            for i, kind in enumerate(cfg.remainder)}
-    return out
+    return {g: {str(i): _gather_kind(kind, state[g][str(i)], axis)
+                for i, kind in enumerate(kinds)}
+            for g, kinds, axis in _groups(cfg) if g in state}
 
 
 def paged_window_view(cfg: ModelConfig, state: dict,
@@ -568,7 +656,7 @@ def paged_window_view(cfg: ModelConfig, state: dict,
     B, n_log = pages.shape
 
     def _gather_kind(kind: str, leaf, axis: int):
-        if kind != ATTN:
+        if kind not in PAGED_KINDS:
             return leaf
 
         def g(pool):
@@ -579,16 +667,9 @@ def paged_window_view(cfg: ModelConfig, state: dict,
             return out.reshape(shape)
         return jax.tree.map(g, leaf)
 
-    out: dict[str, Any] = {}
-    if "blocks" in state:
-        out["blocks"] = {
-            str(i): _gather_kind(kind, state["blocks"][str(i)], 1)
-            for i, kind in enumerate(cfg.pattern)}
-    if "tail" in state:
-        out["tail"] = {
-            str(i): _gather_kind(kind, state["tail"][str(i)], 0)
-            for i, kind in enumerate(cfg.remainder)}
-    return out
+    return {g: {str(i): _gather_kind(kind, state[g][str(i)], axis)
+                for i, kind in enumerate(kinds)}
+            for g, kinds, axis in _groups(cfg) if g in state}
 
 
 def paged_window_scatter(cfg: ModelConfig, state: dict, flat: dict,
@@ -615,7 +696,7 @@ def paged_window_scatter(cfg: ModelConfig, state: dict, flat: dict,
         t0 = jnp.full((B,), t0)
 
     def _scatter_kind(kind: str, pool_leaf, flat_leaf, axis: int):
-        if kind != ATTN:
+        if kind not in PAGED_KINDS:
             return flat_leaf
 
         def s(pool, fl):
@@ -635,24 +716,33 @@ def paged_window_scatter(cfg: ModelConfig, state: dict, flat: dict,
             return pool.at[:, pid].set(fr[:, bb, jj].astype(pool.dtype))
         return jax.tree.map(s, pool_leaf, flat_leaf)
 
-    out: dict[str, Any] = {}
-    if "blocks" in state:
-        out["blocks"] = {
-            str(i): _scatter_kind(kind, state["blocks"][str(i)],
-                                  flat["blocks"][str(i)], 1)
-            for i, kind in enumerate(cfg.pattern)}
-    if "tail" in state:
-        out["tail"] = {
-            str(i): _scatter_kind(kind, state["tail"][str(i)],
-                                  flat["tail"][str(i)], 0)
-            for i, kind in enumerate(cfg.remainder)}
-    return out
+    return {g: {str(i): _scatter_kind(kind, state[g][str(i)],
+                                      flat[g][str(i)], axis)
+                for i, kind in enumerate(kinds)}
+            for g, kinds, axis in _groups(cfg) if g in state}
 
 
 def _decode_block(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
                   state: dict, t: jax.Array, attn_impl: str = "auto",
-                  pages: Optional[jax.Array] = None):
+                  pages: Optional[jax.Array] = None, layer=None,
+                  dense: bool = False):
+    """Returns (x, state, routed pairs on held experts [B,1] or None).
+    For latent attention over the page pool, ``state`` is the group's
+    whole pool (a leading layer axis when ``layer`` is given)."""
     h = layers.apply_norm(cfg, p["norm"], x)
+    if kind == MLA:
+        if pages is None:
+            h, state = mla.decode_attention(cfg, p["attn"], h, state, t)
+        else:
+            pool = state["ckv"] if layer is not None else state["ckv"][None]
+            h, pool = mla.paged_decode_attention(
+                cfg, p["attn"], h, pool, 0 if layer is None else layer, t,
+                pages, impl=attn_impl)
+            state = {"ckv": pool if layer is not None else pool[0]}
+        x = x + h
+        h, _, pairs = _mlp(cfg, p["mlp"],
+                           layers.apply_norm(cfg, p["mlp_norm"], x), dense)
+        return x + h, state, pairs
     if kind == ATTN and pages is not None:
         h, state = attention.paged_decode_attention(cfg, p["attn"], h,
                                                     state, t, pages,
@@ -682,12 +772,13 @@ def _decode_block(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
         else:
             h = layers.apply_mlp(cfg, p["mlp"], h)
         x = x + h
-    return x, state
+    return x, state, None
 
 
 def decode_step(cfg: ModelConfig, params: dict, state: dict,
                 tokens: jax.Array, t: jax.Array, attn_impl: str = "auto",
-                pages: Optional[jax.Array] = None):
+                pages: Optional[jax.Array] = None,
+                return_counts: bool = False):
     """One decode step. tokens [B,1] int32; t = absolute position — scalar
     (lockstep batch) or ``[B]`` vector (continuous batching / ragged rows,
     each cache row at its own position).
@@ -699,36 +790,74 @@ def decode_step(cfg: ModelConfig, params: dict, state: dict,
     ATTN layers read their state as a shared page pool (see
     ``decode_state_spec``'s paged mode) — the table is loop-invariant
     across the repeat scan, so it rides in by closure, not as a carry.
-    Returns (logits [B,1,V], new_state).
+    Where every layer is latent attention (``paged_in_place``), each
+    stacked pool instead rides the scan as a carry and is read and written
+    in place, with the layer index as a scan input: no per-layer slice of
+    the pool is copied.
+    Returns (logits [B,1,V], new_state), and with ``return_counts`` the
+    routed pairs on held experts per row, summed over layers ([B] int32).
     """
     x = layers.embed_tokens(cfg, params["embed"], tokens)
     x = shard(x, "dp", None, None)
     new_state: dict[str, Any] = {}
+    counts = jnp.zeros((x.shape[0],), jnp.int32) if return_counts else None
 
-    if "blocks" in params:
-        def body(h, inputs):
+    def add(counts, pairs):
+        return counts if pairs is None or counts is None \
+            else counts + pairs[:, 0]
+
+    if "lead" in params:
+        new_state["lead"] = {}
+        for i, kind in enumerate(cfg.lead):
+            x, new_state["lead"][str(i)], pr = _decode_block(
+                cfg, kind, params["lead"][str(i)], x, state["lead"][str(i)],
+                t, attn_impl, pages, dense=True)
+            counts = add(counts, pr)
+
+    if "blocks" in params and pages is not None and paged_in_place(cfg):
+        def body(carry, inputs):
+            h, blk_state, n = carry
+            blk_params, li = inputs
+            blk_state = dict(blk_state)
+            for i, kind in enumerate(cfg.pattern):
+                h, blk_state[str(i)], pr = _decode_block(
+                    cfg, kind, blk_params[str(i)], h, blk_state[str(i)], t,
+                    attn_impl, pages, layer=li)
+                n = add(n, pr)
+            return (h, blk_state, n), None
+        (x, new_state["blocks"], counts), _ = _repeat_blocks(
+            body, (x, state["blocks"], counts), params["blocks"],
+            extra=jnp.arange(cfg.num_repeats, dtype=jnp.int32))
+    elif "blocks" in params:
+        def body(carry, inputs):
+            h, n = carry
             blk_params, blk_state = inputs
             new_blk_state = {}
             for i, kind in enumerate(cfg.pattern):
-                h, s = _decode_block(cfg, kind, blk_params[str(i)], h,
-                                     blk_state[str(i)], t, attn_impl,
-                                     pages)
+                h, s, pr = _decode_block(cfg, kind, blk_params[str(i)], h,
+                                         blk_state[str(i)], t, attn_impl,
+                                         pages)
                 new_blk_state[str(i)] = s
-            return h, new_blk_state
-        x, new_state["blocks"] = _repeat_blocks(
-            body, x, params["blocks"], extra=state["blocks"])
+                n = add(n, pr)
+            return (h, n), new_blk_state
+        (x, counts), new_state["blocks"] = _repeat_blocks(
+            body, (x, counts), params["blocks"], extra=state["blocks"])
 
     if "tail" in params:
         new_state["tail"] = {}
         for i, kind in enumerate(cfg.remainder):
-            x, s = _decode_block(cfg, kind, params["tail"][str(i)], x,
-                                 state["tail"][str(i)], t, attn_impl,
-                                 pages)
+            x, s, pr = _decode_block(cfg, kind, params["tail"][str(i)], x,
+                                     state["tail"][str(i)], t, attn_impl,
+                                     pages)
             new_state["tail"][str(i)] = s
+            counts = add(counts, pr)
 
     x = layers.apply_norm(cfg, params["final_norm"], x)
     logits = layers.lm_logits(cfg, params["embed"], x)
-    return shard(logits, "dp", None, "tp"), new_state
+    logits = shard(logits, "dp", None, "tp")
+    if return_counts:
+        return logits, new_state, counts
+    return logits, new_state
 
 
 # ---------------------------------------------------------------------------
@@ -736,11 +865,17 @@ def decode_step(cfg: ModelConfig, params: dict, state: dict,
 # ---------------------------------------------------------------------------
 
 def _extend_block(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
-                  state: dict, t0: jax.Array):
-    if kind not in (ATTN, SWA, LOCAL):
+                  state: dict, t0: jax.Array, dense: bool = False):
+    if kind not in (ATTN, SWA, LOCAL, MLA):
         raise ValueError(
             f"chunked prefill needs an attention-only stack, got {kind!r}")
     h = layers.apply_norm(cfg, p["norm"], x)
+    if kind == MLA:
+        h, state = mla.extend_attention(cfg, p["attn"], h, state, t0)
+        x = x + h
+        h, _, _ = _mlp(cfg, p["mlp"], layers.apply_norm(cfg, p["mlp_norm"], x),
+                       dense)
+        return x + h, state
     h, state = attention.extend_attention(cfg, p["attn"], h, state, t0, kind)
     x = x + h
     h = layers.apply_norm(cfg, p["mlp_norm"], x)
@@ -770,6 +905,13 @@ def prefill_extend(cfg: ModelConfig, params: dict, state: dict,
     x = layers.embed_tokens(cfg, params["embed"], tokens)
     x = shard(x, "dp", None, None)
     new_state: dict[str, Any] = {}
+
+    if "lead" in params:
+        new_state["lead"] = {}
+        for i, kind in enumerate(cfg.lead):
+            x, new_state["lead"][str(i)] = _extend_block(
+                cfg, kind, params["lead"][str(i)], x, state["lead"][str(i)],
+                t0, dense=True)
 
     if "blocks" in params:
         def body(h, inputs):

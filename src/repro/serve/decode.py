@@ -69,6 +69,8 @@ def make_fused_serve_step(cfg: ModelConfig, steps: int,
     (params, state, tokens [B,1], t [B], key?) ->
         (token_block [B,steps], new_state, next_tokens [B,1], t + steps,
          next_key)
+    and, for a stack of expert-share MoE layers, a sixth output: the
+    window's routed token-expert pairs on held experts (int32 scalar).
 
     The sampler and the per-row feed-token / position updates run inside a
     ``lax.scan``, so the PRNG key, ``tokens`` and ``t`` stay device
@@ -80,6 +82,7 @@ def make_fused_serve_step(cfg: ModelConfig, steps: int,
     time, because the scan body IS the single-step path.
     """
     sampler = make_sampler(temperature, top_k)
+    counts = cfg.expert_share
 
     def fused(params, state, tokens, t, key=None, pages=None):
         # ``pages`` (paged KV mode) is read-only inside the window — a
@@ -95,29 +98,42 @@ def make_fused_serve_step(cfg: ModelConfig, steps: int,
         # sync_every=1) keeps the direct paged step — the view would
         # cost two pool copies for a single token, and the direct path
         # is the one the paged flash-decode kernel serves on TPU.
-        use_view = pages is not None and steps > 1
+        # A stack of latent attention only (``paged_in_place``) never
+        # takes the view: its decode step reads and writes the pool in
+        # place through the page table, so a window costs no pool copy.
+        use_view = (pages is not None and steps > 1
+                    and not transformer.paged_in_place(cfg))
         pool_state, t0 = state, t
         if use_view:
             state = transformer.paged_window_view(cfg, state, pages)
         step_pages = None if use_view else pages
 
         def body(carry, _):
-            state, tok, t, key = carry
+            state, tok, t, key, n = carry
             if key is not None:
                 key, sub = jax.random.split(key)
             else:
                 sub = None
-            logits, state = transformer.decode_step(cfg, params, state, tok,
-                                                    t, attn_impl=attn_impl,
-                                                    pages=step_pages)
+            out = transformer.decode_step(cfg, params, state, tok, t,
+                                          attn_impl=attn_impl,
+                                          pages=step_pages,
+                                          return_counts=counts)
+            logits, state = out[:2]
+            if counts:
+                # Routed pairs on held experts, of rows with a request
+                # (pad rows of a compact window sit at t = 0).
+                n = n + jnp.where(t > 0, out[2], 0).sum()
             nxt = sampler(logits, sub)
-            return (state, nxt, t + 1, key), nxt[:, 0]
+            return (state, nxt, t + 1, key, n), nxt[:, 0]
 
-        (state, tok, t, key), toks = jax.lax.scan(
-            body, (state, tokens, t, key), None, length=steps)
+        n0 = jnp.zeros((), jnp.int32) if counts else None
+        (state, tok, t, key, n), toks = jax.lax.scan(
+            body, (state, tokens, t, key, n0), None, length=steps)
         if use_view:
             state = transformer.paged_window_scatter(cfg, pool_state, state,
                                                      pages, t0, steps)
+        if counts:
+            return jnp.moveaxis(toks, 0, 1), state, tok, t, key, n
         return jnp.moveaxis(toks, 0, 1), state, tok, t, key
 
     return fused

@@ -57,9 +57,12 @@ ever fit (prompt + max_new > context_len) fail their own future at
 submit time — they never poison a step, and the queue keeps serving
 everyone else. A full pool queues requests (FCFS) instead of erroring.
 
-Paged KV mode (``page_size`` set): full-context ATTN layers swap their
-flat ``[num_slots, L]`` rings for a shared pool of ``num_pages``
-fixed-size pages plus a host-resident ``[num_slots, n_log]`` page table
+Paged KV mode (``page_size`` set): full-context ATTN layers and latent
+attention (MLA) layers swap their flat ``[num_slots, L]`` rings for a
+shared pool of ``num_pages`` fixed-size pages (``num_pages`` defaults to a
+whole context for every slot, capped at ``POOL_MEMORY_SHARE`` of the
+device memory free when the engine starts) plus a host-resident
+``[num_slots, n_log]`` page table
 (mutated freely on admission/retirement, re-uploaded — a few hundred
 bytes — once per fused window),
 so a row's cache footprint is ``ceil((prompt+max_new)/page_size)``
@@ -78,12 +81,20 @@ pages are fully prompt-covered, and decode writes start at the prompt
 end) and only the suffix runs through ``prefill_extend``. Windowed
 (SWA/local) rings and recurrent state stay per-row — already
 footprint-bounded — which also scopes the prefix cache to causal
-attention-only stacks.
+attention-only stacks. An MLA stack's cache is a latent pool (one
+``[c; rope(k_pe)]`` entry per token and layer); its fused windows read
+and write the pool in place through the page table (the Pallas MLA
+decode kernel on TPU), where an ATTN stack's windows gather a flat
+per-row view once per window and scatter the touched pages back.
 
-MoE caveat: expert routing under a capacity factor couples rows through
-the shared capacity budget, so MoE decode in a shared pool is not
-bit-identical to serving the same request alone (dense / recurrent
-stacks are).
+MoE: Mixtral's capacity-factor layer (``moe.apply_moe``) is a training
+layer; its capacity budget would couple the rows of a batch. The serving
+expert layer (``moe.apply_expert_share``, configs with ``moe_d_ff``)
+drops nothing and routes every token alone, so a row decodes the same
+alone as in a full batch. Its windows count the routed token-expert
+pairs that land on the experts this replica holds
+(``moe_pairs_held``, over ``moe_expert_steps`` = held experts x MoE
+layers x decode steps).
 """
 
 from __future__ import annotations
@@ -100,9 +111,12 @@ from typing import Any, Optional
 import numpy as np
 
 from repro.core import telemetry
-from repro.models.config import ModelConfig
+from repro.models.config import PAGED_KINDS, ModelConfig
 
-_CHUNKABLE_KINDS = {"attn", "swa", "local"}
+_CHUNKABLE_KINDS = {"attn", "swa", "local", "mla"}
+# Share of the device memory free at engine start that a default-sized
+# page pool may take; the rest is left for the compiled steps' scratch.
+POOL_MEMORY_SHARE = 0.55
 
 
 def _pow2ceil(n: int) -> int:
@@ -212,7 +226,7 @@ class ServeEngine:
         self._sync = sync_every
         self._key = jax.random.key(seed) if temperature else None
 
-        kinds = set(cfg.pattern) | set(cfg.remainder)
+        kinds = set(cfg.kinds)
         # The internal ring modulus (_Lp) is context_len rounded UP: to
         # whole pages when paged, else to a length the flat flash-decode
         # kernel tiles without padding the cache on every call. submit()
@@ -227,9 +241,9 @@ class ServeEngine:
             self._ps = int(page_size)
             self._n_log = -(-context_len // self._ps)
             self._Lp = self._n_log * self._ps
-            self._has_paged = "attn" in kinds
+            self._has_paged = bool(kinds & PAGED_KINDS)
             self._P = (int(num_pages) if num_pages is not None
-                       else num_slots * self._n_log)
+                       else self._default_pages())
             if self._has_paged and self._P < self._n_log:
                 # Not fatal — short requests still fit — but a max-size
                 # request can never be admitted; submit() rejects per-request.
@@ -240,15 +254,17 @@ class ServeEngine:
                         else kernel_ops.decode_cache_len(context_len))
             self._has_paged = False
         # Compact windows: with every cache leaf behind the page table
-        # (attention-only stack), the fused window's batch width is a free
-        # choice — the executable sees [W] page-table rows, tokens and
-        # positions, never the pool's row count — so windows run at the
-        # ACTIVE row count (padded up to a compiled width) and idle slots
-        # cost nothing. The flat ring cannot do this without physically
-        # compacting KV rows, which is the structural reason extra paged
-        # admission capacity is ~free. Stacks with per-row state leaves
+        # (a stack of ATTN and MLA layers only), the fused window's batch
+        # width is a free choice — the executable sees [W] page-table
+        # rows, tokens and positions, never the pool's row count — so
+        # windows run at the ACTIVE row count (padded up to a compiled
+        # width) and idle slots cost nothing. The flat ring cannot do
+        # this without physically compacting KV rows, which is the
+        # structural reason extra paged admission capacity is ~free.
+        # Stacks with per-row state leaves
         # (SWA rings, recurrent, conv) keep full-width windows.
-        self._compact = self._has_paged and kinds == {"attn"}
+        self._compact = self._has_paged and kinds <= PAGED_KINDS
+        self._in_place = self._has_paged and transformer.paged_in_place(cfg)
         self._chunk = prefill_chunk
         self._can_chunk = (prefill_chunk is not None
                            and kinds <= _CHUNKABLE_KINDS
@@ -282,10 +298,14 @@ class ServeEngine:
             self._row_pages: list[Optional[list[int]]] = [None] * num_slots
             self._ppr_ewma = 0.0            # pages per admitted request
             prefix_ok = (prefix_cache and cfg.causal and not cfg.conv_pos
-                         and kinds <= {"attn"})
+                         and kinds <= PAGED_KINDS)
             if prefix_ok:
                 from repro.serve.paging import PrefixCache
-                self._prefix: Optional[PrefixCache] = PrefixCache(self._ps)
+                # An entry per page-aligned prefix: room for as many as
+                # the pool has pages, so a long document's entries do not
+                # push each other out.
+                self._prefix: Optional[PrefixCache] = PrefixCache(
+                    self._ps, max_entries=max(512, self._P))
             else:
                 self._prefix = None
         else:
@@ -353,7 +373,8 @@ class ServeEngine:
                               steps=0, decode_tokens=0, generated_tokens=0,
                               occupancy_sum=0, peak_occupancy=0,
                               host_syncs=0, prefix_tokens_reused=0,
-                              param_swaps=0, param_casts=1)
+                              param_swaps=0, param_casts=1,
+                              moe_pairs_held=0, moe_expert_steps=0)
         # Weight hot-swap handoff: (params, applied-event), installed by
         # the engine thread at the top of its next step.
         self._pending_swap: Optional[tuple[Any, threading.Event]] = None
@@ -372,6 +393,28 @@ class ServeEngine:
         if self._device is None:
             return contextlib.nullcontext()
         return jax.default_device(self._device)
+
+    def _default_pages(self) -> int:
+        """A whole context for every slot, capped at ``POOL_MEMORY_SHARE``
+        of the memory the engine's device has free now (devices that do
+        not report their memory, such as the host CPU, are not capped)."""
+        import jax
+        from repro.models import transformer
+        want = self._ns * self._n_log
+        stats = self._device.memory_stats() if self._device else None
+        if not stats or "bytes_limit" not in stats:
+            return want
+
+        def pool_bytes(pages):
+            spec = transformer.decode_state_spec(
+                self._cfg, 1, self._Lp, page_size=self._ps, num_pages=pages)
+            return sum(s.size * s.dtype.itemsize
+                       for s in jax.tree.leaves(spec))
+
+        per_page = pool_bytes(2) - pool_bytes(1)
+        free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+        cap = int(POOL_MEMORY_SHARE * free) // max(per_page, 1) - 1
+        return max(1, min(want, cap))
 
     def _init_state(self):
         """A zeroed full-width decode state: the page pool (P usable
@@ -819,23 +862,26 @@ class ServeEngine:
                     toks_w[w, 0] = s.generated[-1]
                     t_w[w] = s.t
                     pages_w[w] = self._pages_tab[i]
-                toks, self._state, _, _, key = \
-                    self._fused(k_eff)(self._params, self._state, toks_w,
-                                       t_w, self._key, pages_w)
+                out = self._fused(k_eff)(self._params, self._state, toks_w,
+                                         t_w, self._key, pages_w)
+                toks, self._state, _, _, key = out[:5]
                 row_of = {i: w for w, i in enumerate(active)}
             elif self._has_paged:
+                out = self._fused(k_eff)(self._params, self._state,
+                                         self._tokens_dev, self._t_dev,
+                                         self._key, self._pages_tab)
                 toks, self._state, self._tokens_dev, self._t_dev, key = \
-                    self._fused(k_eff)(self._params, self._state,
-                                       self._tokens_dev, self._t_dev,
-                                       self._key, self._pages_tab)
+                    out[:5]
             else:
+                out = self._fused(k_eff)(self._params, self._state,
+                                         self._tokens_dev, self._t_dev,
+                                         self._key)
                 toks, self._state, self._tokens_dev, self._t_dev, key = \
-                    self._fused(k_eff)(self._params, self._state,
-                                       self._tokens_dev, self._t_dev,
-                                       self._key)
+                    out[:5]
             if self._key is not None:
                 self._key = key
             toks = np.asarray(toks)       # ONE host sync per K-token window
+            self._count_window(out, active, k_eff)
         us_tok = ph.dur * 1e6 / (len(active) * k_eff)
         # Each sampled in-flight request gets this window as a span — the
         # loop is a no-op (ctx is None) unless a trace is actually live.
@@ -869,6 +915,30 @@ class ServeEngine:
                     self._retire(i)
                     break
         return len(active)
+
+    def _count_window(self, out, active: list[int], k: int) -> None:
+        """A window's counts, into the counters and, by name, the trace
+        (``telemetry.count``): the routed pairs on held experts (fetched
+        with the tokens) and, for an in-place latent pool, the cache
+        entries the rows attended to — each row's live steps (up to its
+        budget) at lengths t+1 .. t+steps."""
+        if self._cfg.expert_share:
+            pairs = int(np.asarray(out[5]))
+            cfg = self._cfg
+            expert_steps = k * cfg.experts_held \
+                * (cfg.num_layers - cfg.first_dense)
+            with self._lock:
+                self._counters["moe_pairs_held"] += pairs
+                self._counters["moe_expert_steps"] += expert_steps
+            telemetry.count("engine.count.moe_pairs_held", pairs)
+            telemetry.count("engine.count.moe_expert_steps", expert_steps)
+        if self._in_place:
+            n = 0
+            for i in active:
+                s = self._slots[i]
+                live = min(k, s.request.max_new - len(s.generated))
+                n += live * (s.t + 1) + live * (live - 1) // 2
+            telemetry.count("engine.count.latent_tokens", n)
 
     def _retire(self, i: int) -> None:
         slot = self._slots[i]
@@ -904,10 +974,13 @@ class ServeEngine:
         return self
 
     def _warmup(self) -> None:
+        """Runs the windows on the engine's own, still empty state (their
+        writes land in the trash page or in free rows that admission
+        rewrites whole), so warming needs no second pool."""
         import jax
         import jax.numpy as jnp
         from repro.models import transformer
-        state = self._init_state()
+        state = self._state
         if self._has_paged:
             # Compact engines pick a window width per window (the
             # power-of-two ladder up to num_slots), so warm the whole
@@ -929,7 +1002,7 @@ class ServeEngine:
                     key = None if self._key is None else jax.random.key(0)
                     out = self._fused(k)(self._params, state, toks, t, key,
                                          pages)
-                    state = out[1]
+                    state = self._state = out[1]
                     jax.block_until_ready(out)
                     k = min(k * 2, self._sync) if k < self._sync else k * 2
         else:
@@ -939,7 +1012,8 @@ class ServeEngine:
             k = 1
             while k <= self._sync:
                 out = self._fused(k)(self._params, state, toks, t, key)
-                _, state, toks, t, key = out
+                _, state, toks, t, key = out[:5]
+                self._state = state
                 jax.block_until_ready(out)
                 k = min(k * 2, self._sync) if k < self._sync else k * 2
         if self._can_chunk:
@@ -992,15 +1066,22 @@ class ServeEngine:
                 self.ready.set()
 
     def stop(self) -> None:
-        """Stop the loop and fail anything still queued or in flight."""
+        """Stop the loop and fail anything still queued or in flight. Once
+        the loop has exited, the decode state and the weights are dropped,
+        so a stopped engine's device memory is free at once."""
         with self._lock:
             self._closed = True
         self._stop.set()
         self._wake.set()
+        running = False
         if self._thread is not None:
             self._thread.join(timeout=30)
+            running = self._thread.is_alive()
             self._thread = None
         self._fail_all(RuntimeError("engine stopped"))
+        if not running:
+            self._state = self._params = None
+            self._tokens_dev = self._t_dev = None
 
     def _fail_all(self, err: BaseException) -> None:
         """Fail every queued, pending and in-flight request with ``err``

@@ -45,9 +45,19 @@ from typing import Callable, Optional
 import numpy as np
 
 
-def _digest(prompt: np.ndarray, n_tokens: int) -> bytes:
-    return hashlib.blake2b(np.ascontiguousarray(prompt[:n_tokens]).tobytes(),
-                           digest_size=16).digest()
+def _digests(prompt: np.ndarray, page_size: int, n_pages: int) -> list:
+    """Keys of the prompt's first 1 .. ``n_pages`` pages: each the hash of
+    the previous key and one more page of tokens, so a prompt's every
+    page-aligned prefix costs one pass over its tokens (a 16k-token
+    document has a thousand such prefixes)."""
+    toks = np.ascontiguousarray(prompt[:n_pages * page_size])
+    out, key = [], b""
+    for c in range(n_pages):
+        key = hashlib.blake2b(
+            key + toks[c * page_size:(c + 1) * page_size].tobytes(),
+            digest_size=16).digest()
+        out.append(key)
+    return out
 
 
 class PrefixCache:
@@ -76,10 +86,11 @@ class PrefixCache:
         ``prompt``. Returns the physical page ids ([] = miss). The caller
         owns taking a ref on each returned page."""
         c_max = (len(prompt) - 1) // self._ps
+        keys = _digests(prompt, self._ps, c_max)
         for c in range(c_max, 0, -1):
-            chain = self._entries.get(_digest(prompt, c * self._ps))
+            chain = self._entries.get(keys[c - 1])
             if chain is not None:
-                self._entries.move_to_end(_digest(prompt, c * self._ps))
+                self._entries.move_to_end(keys[c - 1])
                 self.hits += 1
                 return list(chain)
         self.misses += 1
@@ -91,8 +102,9 @@ class PrefixCache:
         """Register every page-aligned prefix of ``prompt`` that the
         row's pages fully cover. Chains for prefixes already cached are
         just touched (their pages are the shared ones the row reused)."""
-        for c in range(1, len(prompt) // self._ps + 1):
-            key = _digest(prompt, c * self._ps)
+        keys = _digests(prompt, self._ps, len(prompt) // self._ps)
+        for c in range(1, len(keys) + 1):
+            key = keys[c - 1]
             if key in self._entries:
                 self._entries.move_to_end(key)
                 continue

@@ -132,12 +132,12 @@ def test_shape_applicability_matrix():
     assert rows[("hubert-xlarge", "long_500k")] is not None
     # full-attention archs skip long_500k
     for a in ("qwen2-1.5b", "qwen3-8b", "command-r-plus-104b",
-              "llama-3.2-vision-11b"):
+              "llama-3.2-vision-11b", "deepseek-v2-lite"):
         assert rows[(a, "long_500k")] is not None
     # sub-quadratic archs run long_500k
     for a in ("falcon-mamba-7b", "recurrentgemma-2b", "mixtral-8x7b",
               "mixtral-8x22b", "starcoder2-3b"):
         assert rows[(a, "long_500k")] is None
-    # 34 runnable cells, 6 structurally inapplicable
+    # 37 runnable cells, 7 structurally inapplicable
     runnable = sum(1 for v in rows.values() if v is None)
-    assert runnable == 34
+    assert runnable == 37

@@ -27,7 +27,7 @@ ENGINE_ARCHS = [n for n in DECODE_ARCHS
 # through layers.apply_linear, which casts them to the activations' dtype.
 LINEARS = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "router",
            "head", "in_x", "in_gate", "out", "in_proj", "x_proj",
-           "dt_proj", "out_proj"}
+           "dt_proj", "out_proj", "wkv_a", "wkv_b"}
 NORMS = {"norm", "mlp_norm", "final_norm", "q_norm", "k_norm"}
 L = 24
 MAX_NEW = 3

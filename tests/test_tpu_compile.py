@@ -103,3 +103,21 @@ def test_paged_page_size_rule_matches_compiler(one_chip):
     assert not ops.paged_page_size_ok(16384, DH)
     with pytest.raises(Exception, match="vmem"):
         _compile_paged(one_chip, SLOTS, 1, 16384)
+
+
+@pytest.mark.parametrize("B,n", [(24, 1088), (1, 3)])
+def test_mla_decode_kernel_compiles(one_chip, B, n):
+    """The latent-attention kernel at DeepSeek-V2-Lite's widths (16 heads,
+    640-wide entries, 512-wide values, pages of 16) over the pool of 26
+    stacked layers: the docqa cell's 24 rows of 17408 tokens, and a table
+    narrower than one block of pages (the wrapper pads it)."""
+    def f(q, pool, layer, pages, lengths):
+        return ops.mla_decode(q, pool, layer, pages, lengths, value=512,
+                              sm_scale=0.1, impl="pallas", interpret=False)
+    compiled = jax.jit(f).lower(
+        _spec(one_chip, (B, 16, 640), jnp.bfloat16),
+        _spec(one_chip, (26, B * n + 1, 16, 640), jnp.bfloat16),
+        _spec(one_chip, (), jnp.int32),
+        _spec(one_chip, (B, n), jnp.int32),
+        _spec(one_chip, (B,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
